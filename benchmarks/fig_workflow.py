@@ -230,15 +230,16 @@ def _run_mode(mode: str, workflows: int, ts: float, seed: int) -> Dict:
     }
     if cluster is not None:
         # cluster-merged metrics view: gossip the per-node registry
-        # snapshots through the ICI plane when jax has devices, else take
-        # the fault manager's direct in-process path — same merged view
+        # snapshots through the ICI plane when jax is installed, else take
+        # the fault manager's direct in-process path — same merged view.
+        # A failed device round raises: it is not replaced in silence.
         fm = cluster.fault_manager
         try:
             from repro.core.gossip import MetricsPlane
-
-            MetricsPlane(cluster.live_nodes(), store, fault_manager=fm).step()
-        except Exception:
+        except ImportError:
             fm.collect_metrics()
+        else:
+            MetricsPlane(cluster.live_nodes(), store, fault_manager=fm).step()
         out["obs"] = fm.cluster_metrics()
     platform.shutdown()
     if cluster is not None:

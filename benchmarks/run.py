@@ -18,6 +18,8 @@ import json
 import time
 import traceback
 
+from repro.launch.compile_cache import use_compile_cache
+
 MODULES = {
     "fig2": "benchmarks.fig2_io_latency",
     "fig3": "benchmarks.fig3_table2_e2e",     # includes table2
@@ -53,6 +55,7 @@ def main() -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="CI smoke: tiny counts, fast subset unless --only")
     args = ap.parse_args()
+    use_compile_cache()
     if args.smoke:
         # modules that support it shrink their counts further than quick mode
         import os

@@ -35,10 +35,8 @@ import json
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .ids import TxnId
 from .node import AftNode
@@ -88,6 +86,34 @@ def unpack_digest(rows: np.ndarray) -> List[Tuple[int, int]]:
     return out
 
 
+def digest_mesh(n: int) -> Mesh:
+    """A 1-D ``nodes`` mesh over the largest number of local devices that
+    divides ``n`` (one digest row block per device)."""
+    ndev = len(jax.devices())
+    use = 1
+    for d in range(min(n, ndev), 0, -1):
+        if n % d == 0:
+            use = d
+            break
+    return jax.make_mesh((use,), ("nodes",), devices=jax.devices()[:use])
+
+
+def place_digests(digests: np.ndarray, mesh: Mesh) -> jax.Array:
+    """Shard the stacked digests over the ``nodes`` axis: each device holds
+    its own nodes' rows, which is the all_gather's operand."""
+    return jax.device_put(digests, NamedSharding(mesh, P("nodes")))
+
+
+def digest_gather(mesh: Mesh):
+    """The jitted collective: every device contributes its ``nodes`` shard
+    and receives the gathered whole."""
+    def body(shard):
+        return jax.lax.all_gather(shard, "nodes", axis=0, tiled=True)
+
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("nodes"),
+                                 out_specs=P(), check_vma=False))
+
+
 def exchange_digests(digests: np.ndarray,
                      mesh: Optional[Mesh] = None) -> np.ndarray:
     """All-gather node digests over the ``nodes`` mesh axis.
@@ -96,26 +122,9 @@ def exchange_digests(digests: np.ndarray,
     same array made globally visible — on an n-device mesh each device
     contributes its shard and receives the gathered whole in one collective.
     """
-    n = digests.shape[0]
     if mesh is None:
-        ndev = len(jax.devices())
-        use = 1
-        for d in range(min(n, ndev), 0, -1):
-            if n % d == 0:
-                use = d
-                break
-        mesh = jax.make_mesh((use,), ("nodes",),
-                             devices=jax.devices()[:use])
-
-    @jax.jit
-    def run(x):
-        def body(shard):
-            return jax.lax.all_gather(shard, "nodes", axis=0, tiled=True)
-
-        return shard_map(body, mesh=mesh, in_specs=P("nodes"),
-                         out_specs=P(), check_rep=False)(x)
-
-    return np.asarray(run(jnp.asarray(digests)))
+        mesh = digest_mesh(digests.shape[0])
+    return np.asarray(digest_gather(mesh)(place_digests(digests, mesh)))
 
 
 class DigestPlane:

@@ -1,8 +1,10 @@
 """Jitted public wrappers for the Pallas kernels.
 
 ``use_pallas`` in an ``ArchConfig`` routes the model's attention / SSD
-compute through these.  On CPU (this container) the kernels execute in
-``interpret=True`` mode; on real TPUs ``interpret=False`` compiles Mosaic.
+compute through these.  ``interpret`` is explicit and defaults to False,
+which compiles Mosaic for the TPU; the CPU tests pass ``interpret=True``.
+There is no backend-based fallback: a kernel that cannot compile for the
+device fails loudly rather than running in the interpreter.
 
 The attention wrapper exposes a custom VJP whose backward pass recomputes
 through the pure-jnp reference — flash-style forward memory behavior with a
@@ -13,27 +15,20 @@ iteration; see EXPERIMENTS.md §Perf).
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from .flash_attention import flash_attention
-from .ref import attention_ref, ssd_scan_ref
+from .ref import attention_ref
 from .ssd_scan import ssd_scan
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 4, 5, 6))
 def attention(q, k, v, causal: bool = True, window: int = 0,
-              softcap: float = 0.0, interpret: Optional[bool] = None):
-    interp = (not _on_tpu()) if interpret is None else interpret
+              softcap: float = 0.0, interpret: bool = False):
     return flash_attention(q, k, v, causal=causal, window=window,
-                           softcap=softcap, interpret=interp)
+                           softcap=softcap, interpret=interpret)
 
 
 def _attn_fwd(q, k, v, causal, window, softcap, interpret):
@@ -53,7 +48,6 @@ attention.defvjp(_attn_fwd, _attn_bwd)
 
 
 def ssd(x, da, b_mat, c_mat, *, chunk: int = 256,
-        interpret: Optional[bool] = None):
+        interpret: bool = False):
     """Chunked SSD scan: (y (B,S,H,P) f32, final_state (B,H,P,N) f32)."""
-    interp = (not _on_tpu()) if interpret is None else interpret
-    return ssd_scan(x, da, b_mat, c_mat, chunk=chunk, interpret=interp)
+    return ssd_scan(x, da, b_mat, c_mat, chunk=chunk, interpret=interpret)

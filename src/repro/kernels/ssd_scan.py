@@ -27,13 +27,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, da_ref, b_ref, c_ref, y_ref, st_ref, state_scr, *,
-                chunk: int):
+def _ssd_kernel(x_ref, csc_ref, csr_ref, b_ref, c_ref, y_ref, st_ref,
+                state_scr, *, chunk: int):
     """One (b, h, ic) grid step.
 
-    x_ref: (1, chunk, 1, P) pre-scaled inputs (x·Δt); da_ref: (1, chunk, 1);
-    b_ref/c_ref: (1, chunk, N); y_ref: (1, chunk, 1, P);
-    st_ref: (1, 1, P, N) final-state output; state_scr: (P, N) f32 VMEM.
+    x_ref: (1, 1, chunk, P) pre-scaled inputs (x·Δt); csc_ref/csr_ref: the
+    chunk-local inclusive cumsum of Δt·a as a column (1, 1, chunk, 1) and as
+    a row (1, 1, 1, chunk); b_ref/c_ref: (1, chunk, N); y_ref: (1, 1, chunk,
+    P); st_ref: (1, 1, P, N) final-state output; state_scr: (P, N) f32 VMEM.
     """
     ic = pl.program_id(2)
 
@@ -41,16 +42,15 @@ def _ssd_kernel(x_ref, da_ref, b_ref, c_ref, y_ref, st_ref, state_scr, *,
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)            # (chunk, P)
-    da = da_ref[0, :, 0].astype(jnp.float32)             # (chunk,)
+    x = x_ref[0, 0].astype(jnp.float32)                  # (chunk, P)
+    csum = csc_ref[0, 0]                                 # (chunk, 1)
+    csum_row = csr_ref[0, 0]                             # (1, chunk)
     bm = b_ref[0].astype(jnp.float32)                    # (chunk, N)
     cm = c_ref[0].astype(jnp.float32)                    # (chunk, N)
-
-    csum = jnp.cumsum(da)                                # inclusive
-    total = csum[-1]
+    total = csum[chunk - 1:, :]                          # (1, 1)
 
     # L[q, k] = exp(csum_q − csum_k) for q ≥ k (decay from k to q)
-    seg = csum[:, None] - csum[None, :]
+    seg = csum - csum_row
     qi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     ki = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     lmat = jnp.where(qi >= ki, jnp.exp(seg), 0.0)
@@ -62,18 +62,19 @@ def _ssd_kernel(x_ref, da_ref, b_ref, c_ref, y_ref, st_ref, state_scr, *,
 
     # inter-chunk: entering state contribution + state update
     state = state_scr[...]                               # (P, N)
-    decay_from_start = jnp.exp(csum)                     # (chunk,)
     y_inter = jax.lax.dot_general(cm, state, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    y = y_intra + y_inter * decay_from_start[:, None]
+    y = y_intra + y_inter * jnp.exp(csum)
 
-    decay_to_end = jnp.exp(total - csum)                 # (chunk,)
-    xw = x * decay_to_end[:, None]                       # (chunk, P)
+    xw = x * jnp.exp(total - csum)                       # (chunk, P)
     new_contrib = jax.lax.dot_general(xw, bm, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
-    state_scr[...] = state * jnp.exp(total) + new_contrib
+    # Mosaic cannot broadcast (1, 1) over sublanes and lanes in one op:
+    # materialise the decay as a (P, 1) column, then broadcast over lanes
+    decay_col = jnp.exp(total + jnp.zeros((state.shape[0], 1), jnp.float32))
+    state_scr[...] = state * decay_col + new_contrib
 
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
     st_ref[0, 0] = state_scr[...]
 
 
@@ -96,31 +97,39 @@ def ssd_scan(
     nc = s // chunk
     grid = (bsz, h, nc)
 
+    # head axis ahead of the sequence, so every block's last two dims are
+    # (chunk, P) / (chunk, 1) / (1, chunk): tile-aligned or the array's own
+    xh = x.transpose(0, 2, 1, 3)                         # (B, H, S, P)
+    csum = jnp.cumsum(da.astype(jnp.float32).transpose(0, 2, 1)
+                      .reshape(bsz, h, nc, chunk), axis=-1).reshape(bsz, h, s)
+
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
     y, st = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p),
-                         lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, 1),
-                         lambda bi, hi, ci: (bi, ci, hi)),
+            pl.BlockSpec((1, 1, chunk, p),
+                         lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, chunk, 1),
+                         lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, 1, chunk),
+                         lambda bi, hi, ci: (bi, hi, 0, ci)),
             pl.BlockSpec((1, chunk, n),
                          lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, chunk, n),
                          lambda bi, hi, ci: (bi, ci, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p),
-                         lambda bi, hi, ci: (bi, ci, hi, 0)),
+            pl.BlockSpec((1, 1, chunk, p),
+                         lambda bi, hi, ci: (bi, hi, ci, 0)),
             pl.BlockSpec((1, 1, p, n),
                          lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, s, h, p), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, h, s, p), jnp.float32),
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, da, b_mat, c_mat)
-    return y, st
+    )(xh, csum[..., None], csum[:, :, None, :], b_mat, c_mat)
+    return y.transpose(0, 2, 1, 3), st

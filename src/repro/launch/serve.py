@@ -23,6 +23,7 @@ from repro.serve import ServeConfig, ServeEngine
 from repro.storage.localfs import LocalFSStorage
 from repro.storage.memory import MemoryStorage
 
+from .compile_cache import use_compile_cache
 from .train import reduced_preset
 
 
@@ -39,6 +40,7 @@ def main() -> int:
     ap.add_argument("--max-new", type=int, default=32)
     ap.add_argument("--refresh-every", type=float, default=1.0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg, _, _ = reduced_preset(args.arch, args.preset)
     model = Model(cfg)

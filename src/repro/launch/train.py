@@ -30,6 +30,8 @@ from repro.train import get_optimizer
 from repro.train.data import data_for_model
 from repro.train.loop import CrashInjected, Trainer, TrainerConfig
 
+from .compile_cache import use_compile_cache
+
 
 def make_storage(kind: str, workdir: str):
     if kind == "memory":
@@ -74,6 +76,7 @@ def main() -> int:
                     help="inject a crash after this step (restart to resume)")
     ap.add_argument("--history-out", default="")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg, batch, seq = reduced_preset(args.arch, args.preset)
     if args.batch:

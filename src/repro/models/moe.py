@@ -253,7 +253,6 @@ def _moe_ffn_shard_map(
     ~57 GiB/layer of fp32 all-reduces (see EXPERIMENTS.md §Perf, kimi-k2
     iterations 1–2); the explicit form moves ~100× less.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .sharding import _state, logical_to_spec
@@ -332,11 +331,11 @@ def _moe_ffn_shard_map(
             token_idx.reshape(-1)].add(ys.reshape(-1, d))
         return out.reshape(bl, sl, d), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, *w_specs),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, *w_args)
 
     if cfg.num_shared_experts:

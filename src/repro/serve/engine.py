@@ -109,15 +109,17 @@ class _WeightedEngine:
         # shared (and mutated through) every engine built without a config
         self.config = config if config is not None else ServeConfig()
         self.name = name
-        self._params = params
+        self._params = None if params is None else jax.device_put(params)
         self._weights_step = -1
         self._lock = threading.Lock()
         self._stop_refresh = threading.Event()
         self._refresher: Optional[threading.Thread] = None
         self.registry = registry or Registry(name=name)
         self.stats = EngineStats(
-            {"refreshes": 0, "requests": 0, "tokens_out": 0},
+            {"refreshes": 0, "refresh_errors": 0, "requests": 0,
+             "tokens_out": 0},
             self.registry.snapshot)
+        self.refresh_error: Optional[BaseException] = None
         self.registry.attach_counters(self.stats)
         self._h_prefill = self.registry.histogram("prefill.latency")
         self._h_decode = self.registry.histogram("decode.latency")
@@ -129,9 +131,14 @@ class _WeightedEngine:
                         dur_ms: float = 0.0) -> bool:
         """Swap the serving weights (between iterations — the decode loop
         reads ``self._params`` once per iteration).  Returns False when
-        ``step`` is not newer than the installed set.  Emits a
-        ``weight_refresh`` span carrying the publishing transaction's UUID
-        so the offline checker can correlate the swap with the publish."""
+        ``step`` is not newer than the installed set.  The tree is placed
+        on the device here, once, so no jitted call copies host weights
+        again.  Emits a ``weight_refresh`` span carrying the publishing
+        transaction's UUID so the offline checker can correlate the swap
+        with the publish."""
+        if step <= self._weights_step:
+            return False
+        params = jax.block_until_ready(jax.device_put(params))
         with self._lock:
             if step <= self._weights_step:
                 return False
@@ -172,8 +179,9 @@ class _WeightedEngine:
             while not self._stop_refresh.wait(self.config.refresh_every_s):
                 try:
                     self.refresh_weights()
-                except Exception:
-                    pass  # storage blips are retried next round
+                except Exception as exc:  # retried next round, but counted
+                    self.refresh_error = exc
+                    self.stats["refresh_errors"] += 1
 
         self._refresher = threading.Thread(target=loop, daemon=True)
         self._refresher.start()
@@ -395,6 +403,7 @@ class ContinuousEngine(_WeightedEngine):
         self._work = threading.Event()
         self._loop_stop = threading.Event()
         self._loop: Optional[threading.Thread] = None
+        self.loop_error: Optional[BaseException] = None
         self._base_key = jax.random.key(int(cfg.seed))
         self._iter = 0
         self.stats.update({"decode_iters": 0, "prefill_chunks": 0,
@@ -416,6 +425,10 @@ class ContinuousEngine(_WeightedEngine):
             f"max_len {self._L} with chunk {self._C}")
         ticket = GenTicket(len(prompt))
         with self._qlock:
+            if self.loop_error is not None:
+                ticket.error = self.loop_error
+                ticket._done.set()
+                return ticket
             self._queue.append(_SlotReq(ticket, prompt, int(max_new)))
             self.stats["requests"] += 1
             self.stats["queue_peak"] = max(self.stats["queue_peak"],
@@ -521,10 +534,17 @@ class ContinuousEngine(_WeightedEngine):
         self._loop_stop.clear()
 
         def loop():
-            while not self._loop_stop.is_set():
-                if not self.step():
-                    self._work.clear()
-                    self._work.wait(timeout=0.02)
+            try:
+                while not self._loop_stop.is_set():
+                    if not self.step():
+                        self._work.clear()
+                        self._work.wait(timeout=0.02)
+            except Exception as exc:
+                # a dead loop must not leave waiters hanging until their
+                # timeout: fail everything in flight with the cause
+                with self._qlock:
+                    self.loop_error = exc
+                self._fail_pending(exc)
 
         self._loop = threading.Thread(target=loop, daemon=True,
                                       name=f"{self.name}-decode")
@@ -537,12 +557,15 @@ class ContinuousEngine(_WeightedEngine):
             self._loop.join(timeout=30)
             self._loop = None
         # fail whatever is still in flight so waiters unblock
+        self._fail_pending(RuntimeError(
+            f"engine {self.name} stopped mid-request"))
+        super().stop()
+
+    def _fail_pending(self, error: BaseException) -> None:
         with self._qlock:
             pending = list(self._queue)
             self._queue.clear()
         for req in pending + [r for r in self._slots if r is not None]:
             if not req.ticket.done():
-                req.ticket.error = RuntimeError(
-                    f"engine {self.name} stopped mid-request")
+                req.ticket.error = error
                 req.ticket._done.set()
-        super().stop()

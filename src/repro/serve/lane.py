@@ -200,7 +200,9 @@ class InferenceLane:
         self.registry = registry or Registry(name="serve-lane")
         self.stats = {"requests": 0, "completed": 0, "rerouted": 0,
                       "torn_reads": 0, "refresh_polls": 0,
-                      "refresh_installs": 0, "snapshot_skips": 0}
+                      "refresh_installs": 0, "snapshot_skips": 0,
+                      "refresh_errors": 0}
+        self.refresh_error: Optional[BaseException] = None
         self.registry.attach_counters(self.stats, "lane.")
         self._h_request = self.registry.histogram("lane.request.wall")
         self._stop = threading.Event()
@@ -336,8 +338,9 @@ class InferenceLane:
             while not self._stop.wait(self.config.poll_every_s):
                 try:
                     self.poll_weights()
-                except Exception:
-                    pass  # storage/gossip blips retry next round
+                except Exception as exc:  # retried next round, but counted
+                    self.refresh_error = exc
+                    self.stats["refresh_errors"] += 1
 
         self._poller = threading.Thread(target=loop, daemon=True,
                                         name="lane-refresher")

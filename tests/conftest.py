@@ -12,10 +12,12 @@ if importlib.util.find_spec("jax") is None:
     collect_ignore = [
         "test_arch_smoke.py",
         "test_checkpoint.py",
+        "test_chip_smoke.py",
         "test_kernels.py",
         "test_models_blocks.py",
         "test_property_ckpt.py",
         "test_serve_continuous.py",
         "test_serve_lane.py",
         "test_trainer_serve.py",
+        "test_tpu_compile.py",
     ]

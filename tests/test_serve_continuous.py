@@ -153,3 +153,73 @@ def test_refresh_span_carries_publish_uuid(setup):
     assert spans[0]["publish_uuid"] == "publish.run.3"
     assert spans[0]["step"] == 3
     assert spans[0]["trace"] == obs_trace.txn_trace_id("publish.run.3")
+
+
+def test_install_places_weights_on_device(setup):
+    """Host (NumPy) weights are put on the device once at install, so no
+    jitted call re-copies them from the host."""
+    import numpy as np
+
+    model, params = setup
+    host = jax.tree.map(np.asarray, params)
+    eng = ContinuousEngine(
+        model, None, ServeConfig(max_len=48, slots=2, prefill_chunk=4))
+    assert eng.install_weights(host, 1)
+    installed, step = eng.current_params()
+    assert step == 1
+    leaves = jax.tree.leaves(installed)
+    assert leaves and all(isinstance(x, jax.Array) for x in leaves)
+    assert drive(eng, [eng.submit([5, 6, 7], 2)])[0]
+
+
+def test_decode_loop_failure_fails_tickets(setup):
+    """A decode loop that raises fails every in-flight and queued ticket
+    with the cause, and later submits fail at once instead of hanging."""
+    model, params = setup
+    eng = ContinuousEngine(
+        model, None, ServeConfig(max_len=48, slots=1, prefill_chunk=4),
+        params=params)
+    boom = RuntimeError("device lost")
+
+    def failing_decode(*_args, **_kwargs):
+        raise boom
+
+    eng._decode = failing_decode
+    tickets = [eng.submit([5, 6, 7], 3), eng.submit([8, 9], 2)]
+    eng.start()
+    try:
+        for t in tickets:
+            with pytest.raises(RuntimeError, match="device lost"):
+                t.result(timeout=60)
+        assert eng.loop_error is boom
+        late = eng.submit([1, 2], 2)
+        with pytest.raises(RuntimeError, match="device lost"):
+            late.result(timeout=0)
+    finally:
+        eng.stop()
+
+
+def test_refresher_counts_errors(setup):
+    """A refresh that raises is retried next round, and counted."""
+    import time
+
+    model, params = setup
+
+    class BrokenCheckpointer:
+        def restore(self, like):
+            raise OSError("storage unreachable")
+
+    eng = ContinuousEngine(
+        model, BrokenCheckpointer(),
+        ServeConfig(max_len=48, slots=2, prefill_chunk=4,
+                    refresh_every_s=0.01),
+        params=params)
+    eng.start_refresher()
+    try:
+        deadline = time.monotonic() + 30
+        while eng.stats["refresh_errors"] < 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
+    finally:
+        eng.stop()
+    assert eng.stats["refresh_errors"] >= 2
+    assert isinstance(eng.refresh_error, OSError)

@@ -168,3 +168,22 @@ def test_tokenize_step_string_prompts(lane_setup):
     r = InferenceLane.payload(
         lane.submit("s0", "hi there", max_new=2).result(timeout=60))
     assert len(r["tokens"]) == 2  # tokenizer step mapped str → token ids
+
+
+def test_lane_refresher_counts_errors(lane_setup, monkeypatch):
+    """A poll that raises is retried next round, and counted."""
+    import time
+
+    model, params, cluster, pool, platform, replicas, lane = lane_setup
+
+    def broken_poll():
+        raise OSError("storage unreachable")
+
+    monkeypatch.setattr(lane, "poll_weights", broken_poll)
+    lane.config.poll_every_s = 0.01
+    lane.start_refresher()
+    deadline = time.monotonic() + 30
+    while lane.stats["refresh_errors"] < 2 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert lane.stats["refresh_errors"] >= 2
+    assert isinstance(lane.refresh_error, OSError)
