@@ -15,6 +15,7 @@ from .trace import (
     disable,
     enable,
     get_tracer,
+    region,
     set_tracer,
     span_id,
     trace_id,
@@ -35,6 +36,7 @@ __all__ = [
     "disable",
     "enable",
     "get_tracer",
+    "region",
     "set_tracer",
     "span_id",
     "trace_id",

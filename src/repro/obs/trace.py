@@ -23,10 +23,16 @@ Tracing is **globally off by default**: the module-level tracer is a
 disabled instance whose ``emit`` returns immediately, and every
 instrumentation site guards on ``tracer.enabled``, keeping the disabled
 overhead to one attribute check.
+
+Apart from that event log, ``region(name)`` marks a stretch of host work
+on the JAX profiler's clock (a ``jax.profiler.TraceAnnotation``), so a
+profile shows the serving path's own phases beside the device's programs.
+Region names are static strings under the ``aft.`` prefix.
 """
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -47,6 +53,7 @@ __all__ = [
     "enable",
     "disable",
     "configure_from_env",
+    "region",
 ]
 
 TRACE_FILE_ENV = "REPRO_TRACE_FILE"
@@ -207,3 +214,22 @@ def configure_from_env() -> Tracer:
     if path:
         return enable(path=path)
     return get_tracer()
+
+
+_region_type = None
+
+
+def region(name: str):
+    """A context manager that spans ``name`` on the JAX profiler's clock.
+
+    It is a ``jax.profiler.TraceAnnotation``, which records nothing and
+    costs well under a microsecond while no profile is being taken.  jax is
+    imported on the first call, not with this module; where jax is absent
+    the region is a no-op, so ``repro.obs`` stays importable without it."""
+    global _region_type
+    if _region_type is None:
+        try:
+            from jax.profiler import TraceAnnotation as _region_type
+        except ImportError:
+            _region_type = contextlib.nullcontext
+    return _region_type(name)

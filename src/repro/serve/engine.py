@@ -49,6 +49,7 @@ from repro.checkpoint import AftCheckpointer, CheckpointNotFound
 from repro.models import Model
 from repro.obs import trace as obs_trace
 from repro.obs.registry import Registry
+from repro.obs.trace import region
 
 _stats_deprecation_warned = False
 
@@ -121,8 +122,6 @@ class _WeightedEngine:
             self.registry.snapshot)
         self.refresh_error: Optional[BaseException] = None
         self.registry.attach_counters(self.stats)
-        self._h_prefill = self.registry.histogram("prefill.latency")
-        self._h_decode = self.registry.histogram("decode.latency")
         self._h_refresh = self.registry.histogram("refresh.latency")
 
     # ------------------------------------------------------------- weights
@@ -247,9 +246,7 @@ class ServeEngine(_WeightedEngine):
         self.stats["requests"] += len(prompts)
 
         tokens = jnp.asarray(np.asarray(prompts, np.int32))
-        t0 = time.perf_counter()
         _, state = self._prefill(params, tokens)
-        self._h_prefill.observe_s(time.perf_counter() - t0)
         # the last prompt token's logits come from decode of that token at
         # its position: re-run the final position for the first new token
         out: List[List[int]] = [[] for _ in prompts]
@@ -258,13 +255,11 @@ class ServeEngine(_WeightedEngine):
         position = plen - 1
         for i in range(max_new):
             key, sub = jax.random.split(key)
-            t0 = time.perf_counter()
             logits, state = self._decode(params, state, cur,
                                          jnp.int32(position + i))
             nxt = self._sample(logits, sub)
             cur = nxt[:, None].astype(jnp.int32)
             toks = np.asarray(nxt).tolist()
-            self._h_decode.observe_s(time.perf_counter() - t0)
             for b, tok in enumerate(toks):
                 out[b].append(int(tok))
             self.stats["tokens_out"] += len(prompts)
@@ -276,15 +271,22 @@ class ServeEngine(_WeightedEngine):
 # ---------------------------------------------------------------------------
 
 class GenTicket:
-    """Handle for one in-flight request; resolves when it leaves the batch."""
+    """Handle for one in-flight request; resolves when it leaves the batch.
 
-    __slots__ = ("tokens", "prompt_len", "submitted_at", "finished_at",
-                 "error", "_done")
+    Its stamps are ``time.perf_counter()`` readings, each set once:
+    ``submitted_at`` when it is queued, ``admitted_at`` when it takes a
+    slot, ``first_token_at`` when its last prompt chunk yields the first
+    token, and ``finished_at`` when it leaves the batch."""
+
+    __slots__ = ("tokens", "prompt_len", "submitted_at", "admitted_at",
+                 "first_token_at", "finished_at", "error", "_done")
 
     def __init__(self, prompt_len: int):
         self.tokens: List[int] = []
         self.prompt_len = prompt_len
         self.submitted_at = time.perf_counter()
+        self.admitted_at: Optional[float] = None
+        self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
         self.error: Optional[BaseException] = None
         self._done = threading.Event()
@@ -450,22 +452,47 @@ class ContinuousEngine(_WeightedEngine):
         req.ticket._done.set()
         self.stats["completed"] += 1
 
+    def _emit(self, slot: int, tok: int, pos: int) -> None:
+        """Hand ``tok``, the token at position ``pos``, to the slot's
+        request, and free the slot once the request has all it asked for
+        or the cache is full."""
+        req = self._slots[slot]
+        req.ticket.tokens.append(tok)
+        self.stats["tokens_out"] += 1
+        if len(req.ticket.tokens) >= req.max_new or pos >= self._L:
+            self._finish(slot)
+        else:
+            self._tokens[slot] = tok
+            self._positions[slot] = pos
+
     # ------------------------------------------------------------- the loop
     def step(self) -> bool:
         """One engine iteration: admit queued requests into free slots,
         advance up to ``prefill_chunks_per_iter`` prompt chunks, then run
         one batched decode over every active slot.  Returns True if any
         work was done.  Weights are read once at iteration start — a swap
-        mid-iteration takes effect next iteration, never mid-forward."""
+        mid-iteration takes effect next iteration, never mid-forward.
+
+        Each phase is a ``region`` on the profiler's clock, inside
+        ``aft.engine.step``: ``admit``, ``prefill`` (chunk build, transfer,
+        dispatch), ``prefill_sync`` and ``decode_sync`` (waiting for the
+        sampled tokens), ``decode`` (transfer, dispatch) and ``emit``
+        (handing tokens to requests)."""
+        with region("aft.engine.step"):
+            return self._iterate()
+
+    def _iterate(self) -> bool:
         with self._lock:
             params = self._params
         if params is None:
             return False
         did = False
-        with self._qlock:
+        with region("aft.engine.admit"), self._qlock:
             for s in range(self._S):
                 if self._slots[s] is None and self._queue:
-                    self._slots[s] = self._queue.popleft()
+                    req = self._queue.popleft()
+                    req.ticket.admitted_at = time.perf_counter()
+                    self._slots[s] = req
 
         budget = int(self.config.prefill_chunks_per_iter)
         for s in range(self._S):
@@ -476,54 +503,43 @@ class ContinuousEngine(_WeightedEngine):
                 continue
             did = True
             budget -= 1
-            plen = len(req.prompt)
-            off = req.offset
-            chunk = req.prompt[off:off + self._C]
-            is_final = off + len(chunk) >= plen
-            last_index = len(chunk) - 1
-            if len(chunk) < self._C:  # pad the final chunk to fixed shape
-                chunk = chunk + [0] * (self._C - len(chunk))
-            t0 = time.perf_counter()
-            nxt, self._state = self._prefill(
-                params, self._state, jnp.int32(s),
-                jnp.asarray(chunk, jnp.int32), jnp.int32(off),
-                jnp.int32(last_index), self._key_for(self._iter * 2 + 1))
+            with region("aft.engine.prefill"):
+                plen = len(req.prompt)
+                off = req.offset
+                chunk = req.prompt[off:off + self._C]
+                is_final = off + len(chunk) >= plen
+                last_index = len(chunk) - 1
+                if len(chunk) < self._C:  # pad the final chunk to fixed shape
+                    chunk = chunk + [0] * (self._C - len(chunk))
+                nxt, self._state = self._prefill(
+                    params, self._state, jnp.int32(s),
+                    jnp.asarray(chunk, jnp.int32), jnp.int32(off),
+                    jnp.int32(last_index), self._key_for(self._iter * 2 + 1))
             req.offset = min(off + self._C, plen)
+            self.stats["prefill_chunks"] += 1
             if is_final:
                 # final chunk yields the first generated token (logits at
                 # the last prompt position); the request turns active
-                tok = int(np.asarray(nxt))
-                req.ticket.tokens.append(tok)
-                self.stats["tokens_out"] += 1
-                if len(req.ticket.tokens) >= req.max_new:
-                    self._finish(s)
-                else:
-                    self._tokens[s] = tok
-                    self._positions[s] = plen
-            self._h_prefill.observe_s(time.perf_counter() - t0)
-            self.stats["prefill_chunks"] += 1
+                with region("aft.engine.prefill_sync"):
+                    tok = int(np.asarray(nxt))
+                with region("aft.engine.emit"):
+                    req.ticket.first_token_at = time.perf_counter()
+                    self._emit(s, tok, plen)
 
         active = [s for s in range(self._S) if self._positions[s] < self._L]
         if active:
             did = True
-            t0 = time.perf_counter()
-            nxt, self._state = self._decode(
-                params, self._state, jnp.asarray(self._tokens),
-                jnp.asarray(self._positions), self._key_for(self._iter * 2))
-            nxt = np.asarray(nxt)
-            self._h_decode.observe_s(time.perf_counter() - t0)
+            with region("aft.engine.decode"):
+                nxt, self._state = self._decode(
+                    params, self._state, jnp.asarray(self._tokens),
+                    jnp.asarray(self._positions),
+                    self._key_for(self._iter * 2))
+            with region("aft.engine.decode_sync"):
+                nxt = np.asarray(nxt)
             self.stats["decode_iters"] += 1
-            for s in active:
-                req = self._slots[s]
-                tok = int(nxt[s])
-                req.ticket.tokens.append(tok)
-                self.stats["tokens_out"] += 1
-                if (len(req.ticket.tokens) >= req.max_new
-                        or self._positions[s] + 1 >= self._L):
-                    self._finish(s)
-                else:
-                    self._tokens[s] = tok
-                    self._positions[s] += 1
+            with region("aft.engine.emit"):
+                for s in active:
+                    self._emit(s, int(nxt[s]), int(self._positions[s]) + 1)
         self._iter += 1
         return did
 
@@ -537,8 +553,9 @@ class ContinuousEngine(_WeightedEngine):
             try:
                 while not self._loop_stop.is_set():
                     if not self.step():
-                        self._work.clear()
-                        self._work.wait(timeout=0.02)
+                        with region("aft.engine.wait_work"):
+                            self._work.clear()
+                            self._work.wait(timeout=0.02)
             except Exception as exc:
                 # a dead loop must not leave waiters hanging until their
                 # timeout: fail everything in flight with the cause

@@ -45,6 +45,7 @@ import jax
 from ..checkpoint.serializer import leaf_from_bytes, leaf_to_bytes, tree_paths
 from ..core import SnapshotUnavailable
 from ..obs.registry import Registry
+from ..obs.trace import region
 from ..workflow import WorkflowSpec
 from .refresh import (
     build_publish_workflow,
@@ -225,9 +226,10 @@ class InferenceLane:
         def tokenize(ctx):
             ctx.maybe_fail()
             p = ctx.args["prompt"]
-            if isinstance(p, str):
-                return [1 + (b % 250) for b in p.encode("utf-8")]
-            return [int(t) for t in p]
+            with region("aft.lane.tokenize"):
+                if isinstance(p, str):
+                    return [1 + (b % 250) for b in p.encode("utf-8")]
+                return [int(t) for t in p]
 
         def generate(ctx):
             node = ctx.placed_node
@@ -237,14 +239,21 @@ class InferenceLane:
                 # workflow back through the pool, which re-routes it
                 self.stats["rerouted"] += 1
                 raise RuntimeError(f"no model replica on node {node!r}")
-            raw = ctx.get(mkey)  # read-atomic freshness marker for the span
+            with region("aft.lane.read"):
+                raw = ctx.get(mkey)  # read-atomic freshness marker
             manifest_step = json.loads(raw)["step"] if raw is not None else None
-            ticket = engine.submit(ctx.inputs["tokenize"],
-                                   ctx.args["max_new"])
+            with region("aft.lane.submit"):
+                ticket = engine.submit(ctx.inputs["tokenize"],
+                                       ctx.args["max_new"])
             tokens = ticket.result(timeout=cfg.request_timeout_s)
             return {"tokens": tokens, "node": node,
                     "weights_step": engine.weights_step,
-                    "manifest_step": manifest_step}
+                    "manifest_step": manifest_step,
+                    # the engine's perf_counter stamps of this request
+                    "stamps": {"submitted_at": ticket.submitted_at,
+                               "admitted_at": ticket.admitted_at,
+                               "first_token_at": ticket.first_token_at,
+                               "finished_at": ticket.finished_at}}
 
         spec = WorkflowSpec(f"infer-{session_id}")
         spec.step("tokenize", tokenize, reads=(skey,), read_only=True)
@@ -275,7 +284,10 @@ class InferenceLane:
 
     @staticmethod
     def payload(result) -> Dict[str, Any]:
-        """The generate step's payload from a resolved request ticket."""
+        """The generate step's payload from a resolved request ticket:
+        ``tokens``, ``node``, ``weights_step``, ``manifest_step`` and the
+        engine ticket's ``stamps`` (``submitted_at``, ``admitted_at``,
+        ``first_token_at``, ``finished_at``; ``time.perf_counter``)."""
         return result.results["generate"]
 
     # -------------------------------------------------------------- weights

@@ -223,3 +223,87 @@ def test_refresher_counts_errors(setup):
         eng.stop()
     assert eng.stats["refresh_errors"] >= 2
     assert isinstance(eng.refresh_error, OSError)
+
+
+STAMPS = ("submitted_at", "admitted_at", "first_token_at", "finished_at")
+
+
+def test_ticket_stamps_ordered_and_set_once(setup):
+    """Each request is stamped at submit, at admission to a slot, at its
+    first token and at finish, in that order, and no stamp moves later."""
+    model, params = setup
+    eng = ContinuousEngine(
+        model, None, ServeConfig(max_len=48, slots=2, prefill_chunk=4),
+        params=params)
+    tickets = [eng.submit(p, n) for p, n in PROMPTS]
+    assert all(t.admitted_at is None and t.first_token_at is None
+               for t in tickets)
+    seen = {}
+    while not all(t.done() for t in tickets):
+        assert eng.step()
+        for i, t in enumerate(tickets):
+            for name in STAMPS:
+                value = getattr(t, name)
+                if value is not None:
+                    assert seen.setdefault((i, name), value) == value
+    for t in tickets:
+        stamps = [getattr(t, name) for name in STAMPS]
+        assert stamps == sorted(stamps)
+
+
+def test_one_slot_admits_after_the_previous_finishes(setup):
+    model, params = setup
+    eng = ContinuousEngine(
+        model, None, ServeConfig(max_len=48, slots=1, prefill_chunk=4),
+        params=params)
+    first, second = eng.submit([5, 6, 7, 8, 9], 3), eng.submit([1, 2], 2)
+    drive(eng, [first, second])
+    assert second.admitted_at >= first.finished_at
+    assert second.first_token_at > second.admitted_at
+
+
+ENGINE_SPANS = ("aft.engine.admit", "aft.engine.prefill",
+                "aft.engine.prefill_sync", "aft.engine.decode",
+                "aft.engine.decode_sync", "aft.engine.emit")
+
+
+def test_profile_nests_engine_spans_in_the_step(setup, tmp_path):
+    """A CPU profile of a few iterations holds every phase span of the
+    engine, each inside an ``aft.engine.step`` on the same thread, and the
+    idle loop's ``aft.engine.wait_work`` outside any step."""
+    import glob
+    import time
+
+    from jax.profiler import ProfileData
+
+    model, params = setup
+    eng = ContinuousEngine(
+        model, None, ServeConfig(max_len=48, slots=2, prefill_chunk=4),
+        params=params)
+    drive(eng, [eng.submit([4, 5, 6], 2)])  # compile outside the profile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        drive(eng, [eng.submit(p, n) for p, n in PROMPTS[:3]])
+        eng.start()
+        time.sleep(0.1)
+        eng.stop()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events if e.name.startswith("aft.")]
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:") for line in plane.lines]
+    found = set()
+    for events in lines:
+        steps = [(a, b) for n, a, b in events if n == "aft.engine.step"]
+        for name, a, b in events:
+            inside = any(s <= a and b <= e for s, e in steps)
+            if name in ENGINE_SPANS:
+                assert inside, name
+            elif name == "aft.engine.wait_work":
+                assert not inside
+            found.add(name)
+    assert found >= set(ENGINE_SPANS) | {"aft.engine.step",
+                                         "aft.engine.wait_work"}

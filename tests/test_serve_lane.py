@@ -142,6 +142,24 @@ def test_refresh_under_traffic_and_snapshot_skip(lane_setup):
     assert lane.stats["torn_reads"] == 0
 
 
+def test_answers_carry_engine_stamps(lane_setup):
+    """Each answer carries its engine ticket's stamps, in order, all taken
+    after the lane accepted the request."""
+    import time
+
+    model, params, cluster, pool, platform, replicas, lane = lane_setup
+    install_all(lane, cluster, replicas, params, 1)
+    for eng in replicas.values():
+        eng.start()
+    sent = time.perf_counter()
+    tickets = [lane.submit(f"s{i}", [1 + i] * (2 + 3 * i), max_new=3)
+               for i in range(4)]
+    for t in tickets:
+        st = InferenceLane.payload(t.result(timeout=60))["stamps"]
+        assert sent <= st["submitted_at"] <= st["admitted_at"] \
+            <= st["first_token_at"] <= st["finished_at"]
+
+
 def test_kill_reroutes_to_live_replica(lane_setup):
     model, params, cluster, pool, platform, replicas, lane = lane_setup
     install_all(lane, cluster, replicas, params, 1)
