@@ -129,8 +129,8 @@ def _softcap(scores: jax.Array, cap: float) -> jax.Array:
 
 def _attend_chunk(
     q: jax.Array,          # (B, Cq, KV, G, hd) one query chunk, grouped
-    k: jax.Array,          # (B, S, KV, hd)
-    v: jax.Array,          # (B, S, KV, hd)
+    k: jax.Array,          # (B, KV, S, hd), head-major as the cache is
+    v: jax.Array,          # (B, KV, S, hd)
     q_start: jax.Array,    # global position of the chunk's first query:
                            # scalar, or (B,) when every batch row sits at its
                            # own position (continuous-batching decode)
@@ -141,9 +141,9 @@ def _attend_chunk(
     kv_valid_len: Optional[jax.Array],   # scalar or (B,)
 ) -> jax.Array:
     scale = 1.0 / math.sqrt(q.shape[-1])
-    scores = jnp.einsum("bqngk,bsnk->bngqs", q, k).astype(jnp.float32) * scale
+    scores = jnp.einsum("bqngk,bnsk->bngqs", q, k).astype(jnp.float32) * scale
     scores = _softcap(scores, softcap)
-    s_len = k.shape[1]
+    s_len = k.shape[2]
     q_start = jnp.asarray(q_start)
     # q_pos: (q,) for a shared scalar start, (B, q) for per-row starts
     q_pos = q_start[..., None] + jnp.arange(q.shape[1])
@@ -163,7 +163,7 @@ def _attend_chunk(
         mask = mask[:, None, None, :, :]
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bngqs,bsnk->bqngk", probs, v)
+    return jnp.einsum("bngqs,bnsk->bqngk", probs, v)
 
 
 def multi_head_attention(
@@ -192,14 +192,15 @@ def multi_head_attention(
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     g = h // kvh
     q = q.reshape(b, s, kvh, g, hd)
+    kv_axes = ("batch", "kv_heads", "seq", "head_dim")
+    k = constrain(k.transpose(0, 2, 1, 3), *kv_axes)
+    v = constrain(v.transpose(0, 2, 1, 3), *kv_axes)
 
     if cfg.use_pallas and xkv is None:
         from repro.kernels.ops import attention as pallas_attention
 
         qh = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-        kh = k.transpose(0, 2, 1, 3)
-        vh = v.transpose(0, 2, 1, 3)
-        out = pallas_attention(qh, kh, vh, causal, window, cfg.attn_softcap)
+        out = pallas_attention(qh, k, v, causal, window, cfg.attn_softcap)
         out = out.transpose(0, 2, 1, 3)
         out = constrain(out, "batch", "seq", "heads", "head_dim")
         y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
@@ -228,7 +229,7 @@ def multi_head_attention(
             )
             return carry, o
 
-        kv_ax = Ax(("batch", "seq", "kv_heads", "head_dim"))
+        kv_ax = Ax(kv_axes)
         _, outs = instrumented_scan(
             body,
             (k, v),
@@ -265,45 +266,73 @@ def kv_dequantize(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# decode-step attention over a KV cache
+# attention over a head-major KV cache (decode steps and prefill chunks)
 # ---------------------------------------------------------------------------
 
-def decode_attention(
+def _cache_put(cache: jax.Array, new: jax.Array, lead: Tuple,
+               position: jax.Array) -> jax.Array:
+    """Write ``new`` (B, KV, C, ...) into the head-major ``cache``
+    (*lead, B, KV, S, ...) at sequence offset ``position``.  A (B,)
+    ``position`` writes one row per batch entry (C == 1) with a scatter at
+    (row, head, position), in which a row at ``S`` is out of bounds and
+    drops."""
+    new = new.astype(cache.dtype)
+    if position.ndim:
+        b, kv = new.shape[:2]
+        rows = jnp.arange(b, dtype=jnp.int32)[:, None]
+        heads = jnp.arange(kv, dtype=jnp.int32)[None, :]
+        return cache.at[lead + (rows, heads, position[:, None])].set(
+            new[:, :, 0], mode="drop")
+    start = lead + (0, 0, position) + (0,) * (new.ndim - 3)
+    return jax.lax.dynamic_update_slice(
+        cache, new.reshape((1,) * len(lead) + new.shape), start)
+
+
+def cached_attention(
     params: Dict[str, jax.Array],
-    x: jax.Array,              # (B, 1, d)
-    cache_k: jax.Array,        # (B, S_max, KV, hd) — bf16 or int8
+    x: jax.Array,              # (B, C, d)
+    cache_k: jax.Array,        # (*lead, B, KV, S_max, hd) — bf16 or int8
     cache_v: jax.Array,
     position: jax.Array,       # scalar int, or (B,) per-row positions
     cfg: ArchConfig,
     *,
+    layer: Optional[jax.Array] = None,
     window: int = 0,
     cross: bool = False,
-    k_scale: Optional[jax.Array] = None,   # (B, S_max, KV) — int8 caches
+    k_scale: Optional[jax.Array] = None,   # (*lead, B, KV, S_max) — int8
     v_scale: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array,
            Optional[jax.Array], Optional[jax.Array]]:
-    """One-token decode: append K/V at ``position`` (self-attention) and
-    attend over the valid prefix.  For cross-attention the cache is the
-    encoder/vision projection and is not updated.  With ``k_scale`` the
-    caches are int8 (per token × head absmax) and dequantized on read — on
-    TPU the dequant fuses into the attention matmul's cache stream.
+    """Attention of ``C`` new tokens at positions ``[position,
+    position+C)`` over a KV cache, writing their K/V into it first.
 
-    ``position`` may be a (B,) vector for continuous batching, where each
-    batch row decodes at its own offset.  A per-row position of ``S_max``
-    (the cache length) is a write-proof sentinel: the masked row write
-    touches nothing and the row attends over an empty prefix, which lets a
-    fixed-slot engine run free slots through the same jitted step."""
-    b = x.shape[0]
+    The cache is head-major, (B, KV, S_max, hd), the layout the attention
+    dots read.  With ``layer`` it is the whole stack of a pattern block's
+    caches, (L, B, KV, S_max, hd): the new rows are written in place at
+    ``layer`` and attention reads that layer's slice, so a layer loop that
+    carries the stack copies no cache.  With ``k_scale`` the caches are
+    int8 (per token × head absmax), dequantized on read.
+
+    ``position`` may be a (B,) vector for continuous batching (C == 1),
+    where each batch row decodes at its own offset.  A per-row position of
+    ``S_max`` is a write-proof sentinel: the row write drops and the row's
+    output is ignored, which lets a fixed-slot engine run free slots
+    through the same jitted step.  For a scalar ``position`` the chunk
+    must stay in bounds (``position + C <= S_max``); rows past a prompt's
+    end are masked by causality and overwritten before they enter the
+    valid prefix.  For cross-attention the cache is the encoder/vision
+    projection and is not written."""
+    b, c, _ = x.shape
     position = jnp.asarray(position, dtype=jnp.int32)
-    per_row = position.ndim == 1
-    if per_row:
-        positions = position[:, None]                     # (B, 1)
-    else:
-        positions = jnp.full((b, 1), position, dtype=jnp.int32)
+    lead = () if layer is None else (layer,)
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     if "bq" in params:
         q = q + params["bq"]
-    if not cross:
+    if cross:
+        valid_len = None
+    else:
+        positions = (position[:, None] if position.ndim
+                     else position + jnp.arange(c, dtype=jnp.int32)[None, :])
         q = apply_rope(q, positions, cfg.rope_theta)
         k_new = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
         v_new = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
@@ -311,74 +340,35 @@ def decode_attention(
             k_new = k_new + params["bk"]
             v_new = v_new + params["bv"]
         k_new = apply_rope(k_new, positions, cfg.rope_theta)
-        if per_row:
-            # each row scatters into its own cache slot; the free-slot
-            # sentinel (position == S_max) is out of bounds and drops —
-            # an O(B) scatter, not an O(B*S_max) masked rewrite
-            rows = jnp.arange(b, dtype=jnp.int32)
-            put4 = lambda cache, new: cache.at[rows, position].set(
-                new[:, 0].astype(cache.dtype), mode="drop")
-            put3 = lambda cache, new: cache.at[rows, position].set(
-                new[:, 0], mode="drop")
-            if k_scale is not None:
-                k8, ks_new = kv_quantize(k_new)
-                v8, vs_new = kv_quantize(v_new)
-                cache_k = put4(cache_k, k8)
-                cache_v = put4(cache_v, v8)
-                k_scale = put3(k_scale, ks_new)
-                v_scale = put3(v_scale, vs_new)
-            else:
-                cache_k = put4(cache_k, k_new)
-                cache_v = put4(cache_v, v_new)
-        elif k_scale is not None:
-            k8, ks_new = kv_quantize(k_new)
-            v8, vs_new = kv_quantize(v_new)
-            cache_k = jax.lax.dynamic_update_slice(
-                cache_k, k8, (0, position, 0, 0))
-            cache_v = jax.lax.dynamic_update_slice(
-                cache_v, v8, (0, position, 0, 0))
-            k_scale = jax.lax.dynamic_update_slice(
-                k_scale, ks_new, (0, position, 0))
-            v_scale = jax.lax.dynamic_update_slice(
-                v_scale, vs_new, (0, position, 0))
-        else:
-            cache_k = jax.lax.dynamic_update_slice(
-                cache_k, k_new.astype(cache_k.dtype), (0, position, 0, 0)
-            )
-            cache_v = jax.lax.dynamic_update_slice(
-                cache_v, v_new.astype(cache_v.dtype), (0, position, 0, 0)
-            )
-        cache_k = constrain(cache_k, "cache_batch", "cache_seq", "kv_heads", "head_dim")
-        cache_v = constrain(cache_v, "cache_batch", "cache_seq", "kv_heads", "head_dim")
-        valid_len = position + 1
-    else:
-        valid_len = None
+        k_new = k_new.transpose(0, 2, 1, 3)               # (B, KV, C, hd)
+        v_new = v_new.transpose(0, 2, 1, 3)
+        if k_scale is not None:
+            k_new, ks_new = kv_quantize(k_new)
+            v_new, vs_new = kv_quantize(v_new)
+            k_scale = _cache_put(k_scale, ks_new, lead, position)
+            v_scale = _cache_put(v_scale, vs_new, lead, position)
+        cache_k = _cache_put(cache_k, k_new, lead, position)
+        cache_v = _cache_put(cache_v, v_new, lead, position)
+        valid_len = position + c
 
+    k_eff, v_eff = cache_k[lead], cache_v[lead]
     if k_scale is not None:
-        k_eff = kv_dequantize(cache_k, k_scale, x.dtype)
-        v_eff = kv_dequantize(cache_v, v_scale, x.dtype)
-    else:
-        k_eff, v_eff = cache_k, cache_v
+        k_eff = kv_dequantize(k_eff, k_scale[lead], x.dtype)
+        v_eff = kv_dequantize(v_eff, v_scale[lead], x.dtype)
+    cache_axes = ("cache_batch", "kv_heads", "cache_seq", "head_dim")
+    k_eff, v_eff = constrain(k_eff, *cache_axes), constrain(v_eff, *cache_axes)
 
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     g = h // kvh
-    q = q.reshape(b, 1, kvh, g, hd)
-    if not cross and window > 0:
-        # sliding window: positions ≤ pos−window are masked inside the chunk
-        out = _attend_chunk(
-            q, k_eff, v_eff, position,
-            causal=True, window=window, softcap=cfg.attn_softcap,
-            kv_valid_len=valid_len,
-        )
-    else:
-        out = _attend_chunk(
-            q, k_eff, v_eff, position if not cross else jnp.int32(0),
-            causal=not cross, window=0, softcap=cfg.attn_softcap,
-            kv_valid_len=valid_len,
-        )
-    out = out.reshape(b, 1, h, hd)
+    q = q.reshape(b, c, kvh, g, hd)
+    out = _attend_chunk(
+        q, k_eff, v_eff, jnp.int32(0) if cross else position,
+        causal=not cross, window=0 if cross else window,
+        softcap=cfg.attn_softcap, kv_valid_len=valid_len,
+    )
+    out = out.reshape(b, c, h, hd)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
-    return constrain(y, "batch", None, "embed"), cache_k, cache_v, \
+    return constrain(y, "batch", "seq", "embed"), cache_k, cache_v, \
         k_scale, v_scale
 
 
@@ -388,7 +378,8 @@ def prefill_kv(
     cfg: ArchConfig,
     cache_len: int,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Project K/V for a whole prompt into a fresh cache of ``cache_len``."""
+    """Project K/V for a whole prompt into a fresh head-major cache
+    (B, KV, cache_len, hd)."""
     b, s, _ = x.shape
     positions = jnp.arange(s)
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
@@ -397,71 +388,9 @@ def prefill_kv(
         k = k + params["bk"]
         v = v + params["bv"]
     k = apply_rope(k, positions, cfg.rope_theta)
-    pad = [(0, 0), (0, cache_len - s), (0, 0), (0, 0)]
-    return jnp.pad(k, pad), jnp.pad(v, pad)
-
-
-def prefill_chunk_attention(
-    params: Dict[str, jax.Array],
-    x: jax.Array,              # (B, C, d) — one prompt chunk
-    cache_k: jax.Array,        # (B, S_max, KV, hd) — bf16 or int8
-    cache_v: jax.Array,
-    offset: jax.Array,         # scalar int: global position of chunk row 0
-    cfg: ArchConfig,
-    *,
-    window: int = 0,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array,
-           Optional[jax.Array], Optional[jax.Array]]:
-    """Chunked prefill into an existing decode cache: project/rope the
-    chunk at positions ``[offset, offset+C)``, write its K/V into the
-    cache, and attend the chunk causally over the cache prefix.  Shapes
-    are fixed by (B, C, S_max), so a continuous-batching engine can feed
-    prompts of any length through one jitted call.  The chunk write must
-    stay in bounds (``offset + C <= S_max``); padded rows past the prompt
-    end are masked out by causality for this chunk and overwritten by the
-    decode loop before they ever enter the valid prefix."""
-    b, c, _ = x.shape
-    positions = offset + jnp.arange(c, dtype=jnp.int32)[None, :]  # (1, C)
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
-    k_new = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
-    v_new = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
-    if "bq" in params:
-        q = q + params["bq"]
-        k_new = k_new + params["bk"]
-        v_new = v_new + params["bv"]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k_new = apply_rope(k_new, positions, cfg.rope_theta)
-    if k_scale is not None:
-        k8, ks_new = kv_quantize(k_new)
-        v8, vs_new = kv_quantize(v_new)
-        cache_k = jax.lax.dynamic_update_slice(cache_k, k8, (0, offset, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(cache_v, v8, (0, offset, 0, 0))
-        k_scale = jax.lax.dynamic_update_slice(k_scale, ks_new, (0, offset, 0))
-        v_scale = jax.lax.dynamic_update_slice(v_scale, vs_new, (0, offset, 0))
-        k_eff = kv_dequantize(cache_k, k_scale, x.dtype)
-        v_eff = kv_dequantize(cache_v, v_scale, x.dtype)
-    else:
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k_new.astype(cache_k.dtype), (0, offset, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v_new.astype(cache_v.dtype), (0, offset, 0, 0))
-        k_eff, v_eff = cache_k, cache_v
-    cache_k = constrain(cache_k, "cache_batch", "cache_seq", "kv_heads", "head_dim")
-    cache_v = constrain(cache_v, "cache_batch", "cache_seq", "kv_heads", "head_dim")
-    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    g = h // kvh
-    q = q.reshape(b, c, kvh, g, hd)
-    out = _attend_chunk(
-        q, k_eff, v_eff, offset,
-        causal=True, window=window, softcap=cfg.attn_softcap,
-        kv_valid_len=offset + c,
-    )
-    out = out.reshape(b, c, h, hd)
-    y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
-    return constrain(y, "batch", "seq", "embed"), cache_k, cache_v, \
-        k_scale, v_scale
+    pad = [(0, 0), (0, 0), (0, cache_len - s), (0, 0)]
+    return (jnp.pad(k.transpose(0, 2, 1, 3), pad),
+            jnp.pad(v.transpose(0, 2, 1, 3), pad))
 
 
 # ---------------------------------------------------------------------------

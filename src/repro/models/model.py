@@ -8,11 +8,14 @@ blocks are unrolled.  Every block kind provides three modes:
 
   * ``seq``      — full-sequence forward (training),
   * ``prefill``  — full-sequence forward that also emits the decode state,
-  * ``decode``   — one-token step over the decode state.
+  * ``decode``   — new tokens over the decode state (a decode step, or a
+                   prefill chunk for continuous batching).
 
 Scan bodies take all tensors through carry/xs (no tracer closures — required
 by the roofline tool, see ``models/scan.py``): shared zamba2 weights, encoder
 context, the MoE aux-loss accumulator and the decode position ride the carry.
+The decode state rides the carry too, whole: each layer writes its rows in
+place at its own index, so the state is never copied per layer.
 """
 
 from __future__ import annotations
@@ -29,8 +32,8 @@ from .config import (
     ArchConfig,
 )
 from .layers import (
-    attention_defs, decode_attention, mlp, mlp_defs, multi_head_attention,
-    prefill_chunk_attention, prefill_kv, rmsnorm, rmsnorm_def,
+    attention_defs, cached_attention, mlp, mlp_defs, multi_head_attention,
+    prefill_kv, rmsnorm, rmsnorm_def,
 )
 from .moe import moe_defs, moe_ffn
 from .params import ParamDef, abstract, axes_tree, initialize, is_def, specs
@@ -97,42 +100,29 @@ def _pattern_names(pattern) -> Tuple[str, ...]:
 # ---------------------------------------------------------------------------
 
 def _block_state_defs(kind: str, cfg: ArchConfig, batch: int, max_len: int):
+    """Zero decode state of one block.  Attention caches are head-major,
+    (B, KV, rows, hd), the layout the attention dots read."""
     hd = cfg.resolved_head_dim
     kv = cfg.num_kv_heads
+    axes = ("cache_batch", "kv_heads", "cache_seq", "head_dim")
+
+    def cache(rows, dtype, axes=axes):
+        return ParamDef((batch, kv, rows, hd), axes, dtype, init="zeros")
+
     if kind in (ATTN, LOCAL, DENSE, MOE, SHARED_ATTN):
         kdt = cfg.kv_cache_dtype
-        out = {
-            "k": ParamDef((batch, max_len, kv, hd),
-                          ("cache_batch", "cache_seq", "kv_heads", "head_dim"),
-                          kdt, init="zeros"),
-            "v": ParamDef((batch, max_len, kv, hd),
-                          ("cache_batch", "cache_seq", "kv_heads", "head_dim"),
-                          kdt, init="zeros"),
-        }
+        out = {"k": cache(max_len, kdt), "v": cache(max_len, kdt)}
         if kdt == "int8":
-            out["ks"] = ParamDef((batch, max_len, kv),
-                                 ("cache_batch", "cache_seq", "kv_heads"),
-                                 "float32", init="zeros")
-            out["vs"] = ParamDef((batch, max_len, kv),
-                                 ("cache_batch", "cache_seq", "kv_heads"),
-                                 "float32", init="zeros")
+            for name in ("ks", "vs"):
+                out[name] = ParamDef((batch, kv, max_len), axes[:3],
+                                     "float32", init="zeros")
         return out
     if kind == CROSS:
         enc = cfg.encoder_seq or cfg.vision_seq
-        return {
-            "k": ParamDef((batch, max_len, kv, hd),
-                          ("cache_batch", "cache_seq", "kv_heads", "head_dim"),
-                          cfg.dtype, init="zeros"),
-            "v": ParamDef((batch, max_len, kv, hd),
-                          ("cache_batch", "cache_seq", "kv_heads", "head_dim"),
-                          cfg.dtype, init="zeros"),
-            "ck": ParamDef((batch, enc, kv, hd),
-                           ("cache_batch", "frames", "kv_heads", "head_dim"),
-                           cfg.dtype, init="zeros"),
-            "cv": ParamDef((batch, enc, kv, hd),
-                           ("cache_batch", "frames", "kv_heads", "head_dim"),
-                           cfg.dtype, init="zeros"),
-        }
+        frames = ("cache_batch", "kv_heads", "frames", "head_dim")
+        return {"k": cache(max_len, cfg.dtype), "v": cache(max_len, cfg.dtype),
+                "ck": cache(enc, cfg.dtype, frames),
+                "cv": cache(enc, cfg.dtype, frames)}
     if kind == MAMBA2:
         di, n, h, p = (cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_heads,
                        cfg.ssm_head_dim)
@@ -181,7 +171,8 @@ class Ctx:
     """Non-parameter context threaded through scan carries."""
     shared: Optional[Dict] = None      # zamba2 shared attn+mlp weights
     enc: Optional[jax.Array] = None    # encoder / vision context (B, T, d)
-    position: Optional[jax.Array] = None  # decode position (scalar int32)
+    position: Optional[jax.Array] = None  # decode position: scalar or (B,)
+    layer: Optional[jax.Array] = None  # pattern layer of a stacked state
 
 
 def _attn_mlp_seq(bp, x, cfg, *, window=0, moe_block=False, ctx: Ctx,
@@ -253,8 +244,8 @@ def block_prefill(kind: str, bp, x, cfg: ArchConfig, ctx: Ctx, max_len: int):
         xin = rmsnorm(x, bp["ln1"], eps)
         k, v = prefill_kv(bp["attn"], xin, cfg, max_len)
         enc = ctx.enc
-        ck = jnp.einsum("bsd,dhk->bshk", enc, bp["xattn"]["wk"])
-        cv = jnp.einsum("bsd,dhk->bshk", enc, bp["xattn"]["wv"])
+        ck = jnp.einsum("bsd,dhk->bhsk", enc, bp["xattn"]["wk"])
+        cv = jnp.einsum("bsd,dhk->bhsk", enc, bp["xattn"]["wv"])
         y, aux = block_seq(kind, bp, x, cfg, ctx)
         return y, {"k": k, "v": v, "ck": ck.astype(k.dtype),
                    "cv": cv.astype(v.dtype)}, aux
@@ -284,16 +275,49 @@ def block_prefill(kind: str, bp, x, cfg: ArchConfig, ctx: Ctx, max_len: int):
     raise ValueError(kind)
 
 
+def _layer_state(st, layer):
+    """A block's state for one layer: the slice at ``layer`` of a stacked
+    pattern state, or a tail block's own state (``layer`` None)."""
+    return st if layer is None else jax.tree.map(lambda l: l[layer], st)
+
+
+def _put_layer_state(st, new, layer):
+    """Write one layer's new state back at ``layer`` of the stacked state."""
+    if layer is None:
+        return new
+    return jax.tree.map(
+        lambda l, n: jax.lax.dynamic_update_index_in_dim(
+            l, n.astype(l.dtype), layer, 0), st, new)
+
+
+def _self_attention(ap, xin, st, cfg: ArchConfig, ctx: Ctx, window=0):
+    h, k, v, ks, vs = cached_attention(
+        ap, xin, st["k"], st["v"], ctx.position, cfg, layer=ctx.layer,
+        window=window, k_scale=st.get("ks"), v_scale=st.get("vs"))
+    new_st = {**st, "k": k, "v": v}
+    if ks is not None:
+        new_st["ks"], new_st["vs"] = ks, vs
+    return h, new_st
+
+
+# block kinds whose decode state can be built incrementally, chunk by chunk,
+# into a pre-allocated cache.  Recurrent kinds (mamba2/xlstm) carry conv/
+# hidden tails that this path does not stitch across chunk boundaries.
+CHUNKABLE_KINDS = (ATTN, LOCAL, DENSE, MOE, SHARED_ATTN)
+
+
 def block_decode(kind: str, bp, x, st, cfg: ArchConfig, ctx: Ctx):
-    """One-token step.  x: (B,1,d).  Returns (x, new_state)."""
+    """New tokens over a block's decode state.  x: (B, C, d), C == 1 unless
+    the kind is in CHUNKABLE_KINDS (a prefill chunk); ``ctx.position`` is the
+    first token's position.  ``st`` is the block's whole stacked state when
+    ``ctx.layer`` is set: the block writes its layer's state in place and
+    returns the stack.  Returns (x, new_state)."""
     eps = cfg.norm_eps
-    pos = ctx.position
-    if kind in (ATTN, LOCAL, DENSE, MOE, SHARED_ATTN):
+    if kind in CHUNKABLE_KINDS:
         ap = ctx.shared["attn"] if kind == SHARED_ATTN else bp["attn"]
         window = cfg.sliding_window if kind == LOCAL else 0
-        h, ck, cv, ks, vs = decode_attention(
-            ap, rmsnorm(x, bp["ln1"], eps), st["k"], st["v"], pos, cfg,
-            window=window, k_scale=st.get("ks"), v_scale=st.get("vs"))
+        h, st = _self_attention(ap, rmsnorm(x, bp["ln1"], eps), st, cfg, ctx,
+                                window)
         if cfg.post_block_norm:
             h = rmsnorm(h, bp["post1"], eps)
         x = x + h
@@ -304,74 +328,33 @@ def block_decode(kind: str, bp, x, st, cfg: ArchConfig, ctx: Ctx):
             h = mlp(mp, rmsnorm(x, bp["ln2"], eps), cfg.act)
         if cfg.post_block_norm:
             h = rmsnorm(h, bp["post2"], eps)
-        new_st = {**st, "k": ck, "v": cv}
-        if ks is not None:
-            new_st["ks"], new_st["vs"] = ks, vs
-        return x + h, new_st
+        return x + h, st
     if kind == CROSS:
-        h, ck, cv, ks, vs = decode_attention(
-            bp["attn"], rmsnorm(x, bp["ln1"], eps), st["k"], st["v"], pos,
-            cfg, k_scale=st.get("ks"), v_scale=st.get("vs"))
+        h, st = _self_attention(bp["attn"], rmsnorm(x, bp["ln1"], eps), st,
+                                cfg, ctx)
         x = x + h
-        h, _, _, _, _ = decode_attention(
+        h, _, _, _, _ = cached_attention(
             bp["xattn"], rmsnorm(x, bp["lnx"], eps), st["ck"], st["cv"],
-            pos, cfg, cross=True)
+            ctx.position, cfg, layer=ctx.layer, cross=True)
         x = x + h
-        x = x + mlp(bp["mlp"], rmsnorm(x, bp["ln2"], eps), cfg.act)
-        new_st = {**st, "k": ck, "v": cv}
-        if ks is not None:
-            new_st["ks"], new_st["vs"] = ks, vs
-        return x, new_st
+        return x + mlp(bp["mlp"], rmsnorm(x, bp["ln2"], eps), cfg.act), st
+    cur = _layer_state(st, ctx.layer)
+    xin = rmsnorm(x, bp["ln1"], eps)
     if kind == MAMBA2:
         y, conv, ssm_st = ssm.mamba2_decode_step(
-            bp["mamba"], rmsnorm(x, bp["ln1"], eps), st["conv"], st["ssm"], cfg)
-        return x + y, {"conv": conv, "ssm": ssm_st}
-    if kind == MLSTM:
+            bp["mamba"], xin, cur["conv"], cur["ssm"], cfg)
+        new = {"conv": conv, "ssm": ssm_st}
+    elif kind == MLSTM:
         y, (c, nn, m) = xlstm.mlstm_decode_step(
-            bp["mlstm"], rmsnorm(x, bp["ln1"], eps), (st["c"], st["n"], st["m"]),
-            cfg)
-        return x + y, {"c": c, "n": nn, "m": m}
-    if kind == SLSTM:
+            bp["mlstm"], xin, (cur["c"], cur["n"], cur["m"]), cfg)
+        new = {"c": c, "n": nn, "m": m}
+    elif kind == SLSTM:
         y, (c, nn, hh, m) = xlstm.slstm_decode_step(
-            bp["slstm"], rmsnorm(x, bp["ln1"], eps),
-            (st["c"], st["n"], st["h"], st["m"]), cfg)
-        return x + y, {"c": c, "n": nn, "h": hh, "m": m}
-    raise ValueError(kind)
-
-
-# block kinds whose decode state can be built incrementally, chunk by chunk,
-# into a pre-allocated cache.  Recurrent kinds (mamba2/xlstm) carry conv/
-# hidden tails that this path does not stitch across chunk boundaries.
-CHUNKABLE_KINDS = (ATTN, LOCAL, DENSE, MOE, SHARED_ATTN)
-
-
-def block_prefill_chunk(kind: str, bp, x, st, cfg: ArchConfig, ctx: Ctx):
-    """Chunked prefill over an existing decode state.  x: (B, C, d);
-    ``ctx.position`` is the chunk's global offset (scalar int32).  Returns
-    (x, new_state).  Attention-family kinds only — see CHUNKABLE_KINDS."""
-    eps = cfg.norm_eps
-    if kind not in CHUNKABLE_KINDS:
-        raise NotImplementedError(
-            f"chunked prefill is not supported for block kind {kind!r}")
-    ap = ctx.shared["attn"] if kind == SHARED_ATTN else bp["attn"]
-    window = cfg.sliding_window if kind == LOCAL else 0
-    h, ck, cv, ks, vs = prefill_chunk_attention(
-        ap, rmsnorm(x, bp["ln1"], eps), st["k"], st["v"], ctx.position, cfg,
-        window=window, k_scale=st.get("ks"), v_scale=st.get("vs"))
-    if cfg.post_block_norm:
-        h = rmsnorm(h, bp["post1"], eps)
-    x = x + h
-    if kind == MOE:
-        h, _ = moe_ffn(bp["moe"], rmsnorm(x, bp["ln2"], eps), cfg)
+            bp["slstm"], xin, (cur["c"], cur["n"], cur["h"], cur["m"]), cfg)
+        new = {"c": c, "n": nn, "h": hh, "m": m}
     else:
-        mp = ctx.shared["mlp"] if kind == SHARED_ATTN else bp["mlp"]
-        h = mlp(mp, rmsnorm(x, bp["ln2"], eps), cfg.act)
-    if cfg.post_block_norm:
-        h = rmsnorm(h, bp["post2"], eps)
-    new_st = {**st, "k": ck, "v": cv}
-    if ks is not None:
-        new_st["ks"], new_st["vs"] = ks, vs
-    return x + h, new_st
+        raise ValueError(kind)
+    return x + y, _put_layer_state(st, new, ctx.layer)
 
 
 # ---------------------------------------------------------------------------
@@ -395,10 +378,11 @@ class Model:
         return {name: axes_tree(_block_defs(kind, cfg))
                 for name, kind in zip(self.pattern_names, cfg.pattern)}
 
-    def _unit_state_axes(self):
+    def _state_axes(self):
         cfg = self.cfg
-        return {name: axes_tree(_block_state_defs(kind, cfg, 1, 1))
+        unit = {name: _block_state_defs(kind, cfg, 1, 1)
                 for name, kind in zip(self.pattern_names, cfg.pattern)}
+        return axes_tree(_stack_defs(unit, 1))
 
     def _shared_axes(self):
         if not self.has_shared:
@@ -645,39 +629,7 @@ class Model:
                          - set(CHUNKABLE_KINDS))
             raise NotImplementedError(
                 f"chunked prefill unsupported for block kinds {bad}")
-        shared = params.get("shared") if self.has_shared else None
-        x = self._embed(params, tokens)
-        kinds = dict(zip(self.pattern_names, cfg.pattern))
-
-        def body(carry, xs):
-            x, shared, off = carry
-            bp_slice, st_slice = xs
-            c = Ctx(shared=None if isinstance(shared, jax.Array) else shared,
-                    position=off)
-            new_states = {}
-            for name in self.pattern_names:
-                x, st = block_prefill_chunk(kinds[name], bp_slice[name], x,
-                                            st_slice[name], cfg, c)
-                new_states[name] = st
-            return (x, shared, off), new_states
-
-        shared0 = shared if shared is not None else jnp.float32(0)
-        (x, _, _), new_pattern = instrumented_scan(
-            body, (x, shared0, jnp.asarray(offset, jnp.int32)),
-            (params["pattern"], state["pattern"]), name="prefill_chunk_layers",
-            logical_axes=((Ax(("batch", "seq", "embed")), self._shared_axes(),
-                           AX0),
-                          (self._unit_axes(), self._unit_state_axes())))
-        out = {"pattern": new_pattern}
-        if cfg.tail:
-            ctx = Ctx(shared=shared, position=jnp.asarray(offset, jnp.int32))
-            tail_states = {}
-            for name, kind in zip(self.tail_names, cfg.tail):
-                x, st = block_prefill_chunk(kind, params["tail"][name], x,
-                                            state["tail"][name], cfg, ctx)
-                tail_states[name] = st
-            out["tail"] = tail_states
-        return self._logits(params, x), out
+        return self._cached_step(params, state, tokens, offset)
 
     # --------------------------------------------------------------- decode
     def decode_step(self, params, state, tokens, position, frontend=None):
@@ -685,39 +637,47 @@ class Model:
         or (B,) int32 for continuous batching (each row at its own offset;
         a row position of ``max_len`` is a write-proof free-slot sentinel).
         Returns (logits (B,1,V), new_state)."""
-        cfg = self.cfg
         # NOTE: for enc-dec decode the cross K/V already live in the state;
         # no encoder pass here.
+        return self._cached_step(params, state, tokens, position)
+
+    def _cached_step(self, params, state, tokens, position):
+        """Run ``tokens`` through every block over the decode state.  The
+        stacked pattern state rides in the layer loop's carry and each
+        layer writes its rows in place, so no layer's state is sliced out
+        of the scan's inputs and no second state is collected as its
+        outputs."""
+        cfg = self.cfg
         shared = params.get("shared") if self.has_shared else None
         x = self._embed(params, tokens)
+        position = jnp.asarray(position, jnp.int32)
         kinds = dict(zip(self.pattern_names, cfg.pattern))
 
         def body(carry, xs):
-            x, shared, pos = carry
-            bp_slice, st_slice = xs
+            x, shared, pos, states = carry
+            layer, bp_slice = xs
             c = Ctx(shared=None if isinstance(shared, jax.Array) else shared,
-                    position=pos)
-            new_states = {}
+                    position=pos, layer=layer)
+            states = dict(states)
             for name in self.pattern_names:
-                x, st = block_decode(kinds[name], bp_slice[name], x,
-                                     st_slice[name], cfg, c)
-                new_states[name] = st
-            return (x, shared, pos), new_states
+                x, states[name] = block_decode(kinds[name], bp_slice[name], x,
+                                               states[name], cfg, c)
+            return (x, shared, pos, states), None
 
         shared0 = shared if shared is not None else jnp.float32(0)
-        (x, _, _), new_pattern = instrumented_scan(
-            body, (x, shared0, jnp.asarray(position, jnp.int32)),
-            (params["pattern"], state["pattern"]), name="decode_layers",
-            logical_axes=((Ax(("batch", None, "embed")), self._shared_axes(),
-                           AX0),
-                          (self._unit_axes(), self._unit_state_axes())))
-        out = {"pattern": new_pattern}
+        layers = jnp.arange(cfg.pattern_repeats, dtype=jnp.int32)
+        (x, _, _, pattern), _ = instrumented_scan(
+            body, (x, shared0, position, state["pattern"]),
+            (layers, params["pattern"]), name="decode_layers",
+            logical_axes=((Ax(("batch", "seq", "embed")), self._shared_axes(),
+                           AX0, self._state_axes()),
+                          (AX0, self._unit_axes())))
+        out = {"pattern": pattern}
         if cfg.tail:
-            ctx = Ctx(shared=shared, position=jnp.asarray(position, jnp.int32))
-            tail_states = {}
+            ctx = Ctx(shared=shared, position=position)
+            tail = dict(state["tail"])
             for name, kind in zip(self.tail_names, cfg.tail):
-                x, st = block_decode(kind, params["tail"][name], x,
-                                     state["tail"][name], cfg, ctx)
-                tail_states[name] = st
-            out["tail"] = tail_states
+                x, tail[name] = block_decode(kind, params["tail"][name], x,
+                                             tail[name], cfg, ctx)
+            out["tail"] = tail
         return self._logits(params, x), out

@@ -106,3 +106,100 @@ def test_moe_capacity_drop_is_bounded():
     x = jax.random.normal(jax.random.key(6), (2, 32, cfg.d_model))
     out, _ = moe_ffn(params, x, cfg)
     assert bool(jnp.isfinite(out).all())
+
+
+# one case per block kind the decode step serves, and the int8 cache:
+# case -> (architecture, overrides of its reduced configuration, atol
+# against the float32 forward pass, which keeps K/V unrounded)
+DECODE_CASES = {
+    "attn": ("qwen2-0.5b", {}, 2e-2),
+    "local": ("gemma2-9b", {}, 2e-2),
+    "moe": ("kimi-k2-1t-a32b", {"capacity_factor": 8.0}, 2e-2),
+    "shared_attn-mamba2": ("zamba2-7b", {}, 2e-2),
+    "cross": ("llama-3.2-vision-11b", {}, 2e-2),
+    "mlstm-slstm": ("xlstm-350m", {}, 2e-2),
+    "int8": ("qwen2-0.5b", {"kv_cache_dtype": "int8"}, 0.3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_decode_rows_write_in_place_and_match_forward(case):
+    """Per-row decode steps over a slot state, one slot at the ``max_len``
+    free-slot sentinel: each live row's logits match the forward pass over
+    its whole sequence, each step writes exactly one cache row per live
+    slot in every layer and none of the sentinel's, and a scalar-position
+    step over the slot alone gives the same logits and state."""
+    from repro.models import Model
+    from repro.models.params import is_def
+
+    arch, over, atol = DECODE_CASES[case]
+    cfg = get_config(arch).reduced(**over)
+    model = Model(cfg)
+    params = model.init_params(jax.random.key(0))
+    M, T, starts = 24, 4, (18, 11)  # gemma2's window (16) lies inside
+    seqs = [jax.random.randint(jax.random.key(1 + r), (1, p + T), 0,
+                               cfg.vocab_size) for r, p in enumerate(starts)]
+    enc = cfg.encoder_seq or cfg.vision_seq
+    fronts = [jax.random.normal(jax.random.key(10 + r),
+                                (1, enc, cfg.d_model)) if enc else None
+              for r in range(len(starts))]
+    forward = jax.jit(model.forward)
+    prefill = jax.jit(model.prefill, static_argnums=2)
+    want = [forward(params, s, f)[0][0] for s, f in zip(seqs, fronts)]
+    slots = [prefill(params, s[:, :p], M, f)[1]
+             for s, p, f in zip(seqs, starts, fronts)]
+    slots.append(model.init_decode_state(1, M))   # the free slot
+
+    def stack(tree_list):
+        out = {"pattern": jax.tree.map(lambda *l: jnp.concatenate(l, 1),
+                                       *[t["pattern"] for t in tree_list])}
+        if "tail" in tree_list[0]:
+            out["tail"] = jax.tree.map(lambda *l: jnp.concatenate(l, 0),
+                                       *[t["tail"] for t in tree_list])
+        return out
+
+    defs = jax.tree.leaves(model.decode_state_defs(len(slots), M),
+                           is_leaf=is_def)
+    step = jax.jit(model.decode_step)
+    state, singles = stack(slots), slots[:-1]
+    for i in range(T):
+        pos = [p + i for p in starts] + [M]
+        toks = jnp.array([[int(s[0, p])] for s, p in zip(seqs, pos)] + [[0]],
+                         jnp.int32)
+        logits, new = step(params, state, toks, jnp.array(pos, jnp.int32))
+        for r in range(len(starts)):
+            np.testing.assert_allclose(
+                np.asarray(logits[r, 0, :cfg.vocab_size]),
+                np.asarray(want[r][pos[r], :cfg.vocab_size]),
+                rtol=2e-2, atol=atol)
+            one, singles[r] = step(params, singles[r], toks[r:r + 1],
+                                   jnp.int32(pos[r]))
+            np.testing.assert_allclose(np.asarray(one[0]),
+                                       np.asarray(logits[r]),
+                                       rtol=1e-4, atol=1e-4)
+        for d, old, cur in zip(defs, jax.tree.leaves(state),
+                               jax.tree.leaves(new)):
+            changed = np.asarray(old != cur)
+            if "frames" in d.axes:            # cross K/V: never written
+                assert not changed.any()
+            if "cache_seq" not in d.axes:     # recurrent state: every slot
+                continue
+            keep = [d.axes.index(a)
+                    for a in ("layers", "cache_batch", "cache_seq")
+                    if a in d.axes]
+            rows = changed.any(axis=tuple(a for a in range(changed.ndim)
+                                          if a not in keep))
+            expect = np.zeros_like(rows)
+            for r in range(len(starts)):
+                expect[..., r, pos[r]] = True
+            np.testing.assert_array_equal(rows, expect)
+        state = new
+    for r, single in enumerate(singles):
+        mine = {"pattern": jax.tree.map(lambda l: l[:, r:r + 1],
+                                        state["pattern"])}
+        if "tail" in state:
+            mine["tail"] = jax.tree.map(lambda l: l[r:r + 1], state["tail"])
+        for got, one in zip(jax.tree.leaves(single), jax.tree.leaves(mine)):
+            np.testing.assert_allclose(np.asarray(got, np.float32),
+                                       np.asarray(one, np.float32),
+                                       rtol=1e-4, atol=1e-4)

@@ -3,16 +3,20 @@
 The TPU compiler refuses what interpret mode accepts: block shapes off the
 (8, 128) tiling, too much fast memory, a program larger than the device.
 These cases compile the Pallas kernels at published widths, the full-width
-tinyllama-1.1b serving steps and the 4-device digest all_gather for a
-``v5e:2x2`` topology.  Nothing runs, so they say nothing about results or
-times.
+tinyllama-1.1b serving steps, the serving steps of the benchmark's cells
+and the 4-device digest all_gather for a ``v5e:2x2`` topology.  Nothing
+runs, so they say nothing about results or times.
 
 The topology is described inside a module fixture (never at import time):
 only one process at a time may load the TPU library, so under several test
 workers only the worker that runs this file loads it.
 """
 
+import dataclasses
+import json
 import os
+import re
+from pathlib import Path
 
 import pytest
 
@@ -131,6 +135,65 @@ def test_tinyllama_serving_step_compiles(one_chip, tinyllama_engine, step):
                                      i32(), key)
     mem = lowered.compile().memory_analysis()
     assert 2e9 < mem.argument_size_in_bytes < DEVICE_BYTES
+
+
+BENCH = Path(__file__).resolve().parents[1] / "bench"
+
+
+def _cell_engine(cell):
+    """The ContinuousEngine of a benchmark cell: the registered
+    configuration with the sizes of the cell's configuration file, at the
+    cell's slots, rows and chunk.  Its decode state is abstract."""
+    from repro.models import Model
+    from repro.models.config import get_config
+    from repro.serve.engine import ContinuousEngine, ServeConfig
+
+    cj = json.loads((BENCH / "configs" / f"{cell.rsplit('.', 1)[0]}.json")
+                    .read_text())
+    sv = json.loads((BENCH / "cells" / f"{cell}.json").read_text())["serve"]
+    cfg = dataclasses.replace(get_config(cj["registered"]),
+                              pattern_repeats=cj["num_hidden_layers"],
+                              vocab_size=cj["vocab_size"])
+    model = Model(cfg)
+    model.init_decode_state = lambda S, L: jax.eval_shape(
+        lambda: Model.init_decode_state(model, S, L))
+    return ContinuousEngine(model, None, ServeConfig(**sv))
+
+
+@pytest.mark.parametrize("cell,step", [("qwen2-0.5b.chat", "decode"),
+                                       ("qwen1.5-110b-4l.rag", "decode"),
+                                       ("qwen2-0.5b.chat", "prefill")])
+def test_cell_serving_step_copies_no_cache(one_chip, cell, step):
+    """The serving steps write the KV cache in place: no copy in the
+    program has a ``max_len`` dimension, the new state aliases the donated
+    one, and the decode step's scratch memory is a small share of the
+    state."""
+    eng = _cell_engine(cell)
+    params = _shapes(jax.eval_shape(eng.model.init_params,
+                                    jax.random.key(0)), one_chip)
+    state = _shapes(eng._state, one_chip)
+    key = _shapes(jax.eval_shape(lambda: jax.random.key(0)), one_chip)
+    S, L, C = eng._S, eng._L, eng._C
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if step == "decode":
+        lowered = eng._decode.lower(params, state, i32(S), i32(S), key)
+    else:
+        lowered = eng._prefill.lower(params, state, i32(), i32(C), i32(),
+                                     i32(), key)
+    compiled = lowered.compile()
+    copies = [m.group(1) for m in re.finditer(
+        r"= \w+\[([\d,]*)\]\S* copy\(", compiled.as_text())]
+    assert copies, "no copy matched: the HLO text format changed"
+    assert not [c for c in copies if str(L) in c.split(",")], copies
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(state))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    if step == "decode":
+        assert mem.temp_size_in_bytes < state_bytes / 4
 
 
 def test_digest_all_gather_compiles_on_four_chips(topo):
