@@ -79,7 +79,7 @@ def gaps(params_by_step: Dict[int, object], cj: dict, packs: List[List],
             exact[step] += reference.split_rows(ex[:pk[5]], lengths)
             logits.append(lg)
         if control:
-            qparams, scales = reference.quantize(params)
+            qparams, scales = reference.quantize(params, cj)
             ctrl[step] = max(
                 float(reference.control_gaps(
                     lg, reference.forward(qparams, cj, pk, window, scales)

@@ -25,6 +25,7 @@ def roofline(run, kind: str, program: str) -> Optional[float]:
     calls = [c for c in run.calls if c[1] == kind and a <= c[0] < b]
     if not runs or not calls:
         return None
-    need = sum(work.least_seconds(work.call_work(run.dims, c), run.peak)
+    need = sum(work.least_seconds(work.call_work(run.cell.config, c),
+                                   run.peak)
                for c in calls) / len(calls)
     return 100.0 * need / (sum(runs) / len(runs))

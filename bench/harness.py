@@ -27,43 +27,41 @@ from typing import Any, Dict, List, Optional
 import jax
 import numpy as np
 
+import spec
 import traffic as traffic_mod
 
 GRACE_S = 60.0          # wait past the window for answers still due
 TRACE_S = 4.0           # longest traced stretch of a window
+# wait past the window for the profiler to write its trace: 4 s of a
+# 5.5 ms decode hold most of a million device events, and stop_trace took
+# 78 s with the Python tracer on and 92 s with it off on one TPU v5e
+STOP_TRACE_S = 180.0
+
+
+class TraceTimeout(TimeoutError):
+    """The profiler did not write its trace within ``STOP_TRACE_S``."""
 
 
 # ---------------------------------------------------------------------------
 # the program's configuration, from the configuration file
 # ---------------------------------------------------------------------------
 
-KEYMAP = {"hidden_size": "d_model", "num_attention_heads": "num_heads",
-          "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-          "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-          "num_hidden_layers": "pattern_repeats",
-          "tie_word_embeddings": "tie_embeddings", "rope_theta": "rope_theta",
-          "rms_norm_eps": "norm_eps", "torch_dtype": "dtype",
-          "qkv_bias": "qkv_bias"}
-
-
 def arch_config(cj: dict):
-    """The registered configuration with the file's sizes.  A size that
-    differs from the registered one must be listed in ``reduced``."""
+    """The registered configuration with the file's sizes, mapped by the
+    configuration's family (``arch``).  A size that differs from the
+    registered one must be listed in ``reduced``."""
     import dataclasses
 
-    from repro.models.config import ATTN, get_config
+    from repro.models.config import get_config
 
     base = get_config(cj["registered"])
-    if base.pattern != (ATTN,) or base.tail:
-        raise ValueError(f"{base.name}: only a plain attention stack maps "
-                         f"onto num_hidden_layers")
-    over = {KEYMAP[k]: cj[k] for k in KEYMAP if k in cj}
-    for key, name in KEYMAP.items():
-        if key in cj and key not in cj.get("reduced", ()) \
-                and getattr(base, name) != cj[key]:
-            raise ValueError(f"{key}={cj[key]} differs from {base.name}'s "
+    over = spec.family(cj).arch(cj, base)
+    for key, (name, value) in over.items():
+        if key not in cj.get("reduced", ()) and getattr(base, name) != value:
+            raise ValueError(f"{key}={value} differs from {base.name}'s "
                              f"{getattr(base, name)} and is not in reduced")
-    return dataclasses.replace(base, name=f"{base.name}@bench", **over)
+    return dataclasses.replace(base, name=f"{base.name}@bench",
+                               **dict(over.values()))
 
 
 def check_layout(model, params) -> None:
@@ -284,7 +282,6 @@ class RunData:
     publish: Optional[dict] = None
     trace: Optional[dict] = None
     trace_t: Optional[tuple] = None
-    dims: Optional[dict] = None
     peak: Optional[dict] = None
     compiles_in_window: int = 0
 
@@ -337,7 +334,10 @@ def _closed_loop(system, reqs, outstanding, t0, t1) -> List[Sent]:
 
 
 class _Tracer(threading.Thread):
-    """Profiles ``TRACE_S`` seconds from the middle of the window."""
+    """Profiles ``TRACE_S`` seconds from the middle of the window.  The
+    traced span is recorded when its sleep ends, before ``stop_trace``
+    writes the trace.  Host events are the host tracer's alone (the
+    ``bench.`` and ``aft.`` annotations); the Python tracer is off."""
 
     def __init__(self, log_dir: str, start: float, length: float):
         super().__init__(daemon=True, name="bench-trace")
@@ -348,15 +348,16 @@ class _Tracer(threading.Thread):
     def run(self):
         try:
             time.sleep(max(0.0, self.start_at - time.perf_counter()))
-            jax.profiler.start_trace(self.log_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.log_dir, profiler_options=options)
             try:
                 with jax.profiler.TraceAnnotation("bench.trace_window"):
                     a = time.perf_counter()
                     time.sleep(self.length)
-                    b = time.perf_counter()
+                    self.span = (a, time.perf_counter())
             finally:
                 jax.profiler.stop_trace()
-            self.span = (a, b)
         except Exception as exc:  # re-raised by run_window
             self.error = exc
 
@@ -398,7 +399,8 @@ def run_window(system: System, cell, seed: int, seconds: float, *,
                vocab: int, params2=None, trace_dir: Optional[str] = None,
                rate: Optional[float] = None) -> RunData:
     """Offer the cell's traffic for ``seconds``, then wait for every
-    request sent to resolve (``GRACE_S`` at most past the window)."""
+    request sent to resolve (``GRACE_S`` at most past the window), and for
+    the profiler to write its trace (``STOP_TRACE_S``)."""
     s, tr = cell.settings, cell.traffic
     run = RunData(cell=cell, seconds=seconds)
     if tr["loop"] == "open":
@@ -440,11 +442,16 @@ def run_window(system: System, cell, seed: int, seconds: float, *,
     while any(r.done is None for r in sent) \
             and time.perf_counter() < deadline:
         time.sleep(0.01)
+    if publisher is not None:
+        publisher.join(max(1.0, deadline - time.perf_counter()))
+    if tracer is not None:
+        tracer.join(max(1.0, t1 + STOP_TRACE_S - time.perf_counter()))
+        if tracer.is_alive():
+            raise TraceTimeout(f"the profiler had not written its trace "
+                               f"{STOP_TRACE_S:.0f} s after the window")
     for th in (publisher, tracer):
-        if th is not None:
-            th.join(max(1.0, deadline - time.perf_counter()))
-            if th.error is not None:
-                raise th.error
+        if th is not None and th.error is not None:
+            raise th.error
     _collect(system, sent)
     run.t0, run.t1 = t0, t1
     run.requests = sent

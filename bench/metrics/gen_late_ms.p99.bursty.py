@@ -1,0 +1,7 @@
+"""``gen_late_ms.p99``, read in the cells whose bursty traffic leaves the latency
+tail too unsteady to bound end to end: the same reading, tied there to
+``tokens_per_s``."""
+
+import spec
+
+read = spec.reader("gen_late_ms.p99")

@@ -10,7 +10,7 @@ def read(run):
     if run.trace is None or run.trace_t is None or run.peak is None:
         return None
     a, b = run.trace_t
-    flops = sum(work.call_work(run.dims, c)["flops"] for c in run.calls
-                if a <= c[0] < b)
+    flops = sum(work.call_work(run.cell.config, c)["flops"]
+                for c in run.calls if a <= c[0] < b)
     return 100.0 * flops / (run.trace["window_s"]
                             * run.peak["bf16_flops_per_s"])

@@ -1,21 +1,13 @@
-"""The plain reference: the Qwen2 forward pass in float32, written from the
-published architecture, with no cache, no batching and no code of the
-program.
-
-    x = embed[tokens]
-    per layer:  x += o(attn(rope(q(n1(x)) + bq), rope(k(n1(x)) + bk),
-                             v(n1(x)) + bv))       # causal, GQA
-                x += w2(silu(w1(n2(x))) * w3(n2(x)))
-    logits = head(norm(x))                          # head = embed^T if tied
-
-RMSNorm is ``w * x / sqrt(mean(x^2) + eps)``; rope rotates the two halves
-of each head (``rotate_half``) with frequencies ``theta^(-2i/hd)``.
+"""The plain reference: the configuration's forward pass in float32, with
+no cache, no batching and no code of the program.  Its equations are the
+family's (``families/<model_type>.py``, ``forward``), written from the
+published architecture with the helpers here.
 
 It teacher-forces packed sequences (prompt followed by the served tokens)
 and returns logits only at the rows whose next token was served.  It runs
-after the measured window, layer by layer (a scan over the stacked
-weights), in blocks of query rows for attention and of token rows for the
-MLP, so that it fits beside the weights on one chip.
+after the measured window, layer by layer, in blocks of query rows for
+attention and of token rows for the MLP, so that it fits beside the
+weights on one chip.
 
 Every matrix product is float32 to float32 rounding without a float32 copy
 of a weight: the weights are bfloat16 (or float8 in the control), so each
@@ -23,9 +15,9 @@ is exact in bfloat16, and the float32 activation is split into three
 bfloat16 parts whose products are exact and summed in float32.  Attention
 scores and their weighted sum use ``Precision.HIGHEST``.
 
-The control (``quantize``) is this same reference with every matrix in
-float8 e4m3, scaled per output column: the step below the configuration's
-bfloat16.
+The control (``quantize``) is this same reference with every matrix the
+family lists in ``MATRICES`` in float8 e4m3, scaled per output column: the
+step below the configuration's bfloat16.
 """
 
 from __future__ import annotations
@@ -38,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import weights
+import spec
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -46,9 +38,6 @@ Q_BLOCK = 256      # query rows per attention block
 MLP_BLOCK = 512    # token rows per MLP block
 WANT_MAX = 1024    # logits rows computed per pass
 FP8_MAX = 448.0    # largest finite float8 e4m3 value
-
-# matrices quantized in the control; each output column has its own scale
-_MATRICES = frozenset({"wq", "wk", "wv", "wo", "w1", "w2", "w3"})
 
 
 # ---------------------------------------------------------------------------
@@ -96,10 +85,11 @@ def _split3(x):
 
 
 def _mm(x, w, scale=None):
-    """float32 ``x @ w`` for a ``w`` that is exact in bfloat16."""
+    """float32 ``x @ w`` for a ``w`` that is exact in bfloat16; ``scale``
+    holds one factor per output column, in the output axes' shape."""
     w = w.astype(BF16)
     y = sum(jnp.dot(p, w, preferred_element_type=F32) for p in _split3(x))
-    return y if scale is None else y * scale
+    return y if scale is None else y * scale.reshape(-1)
 
 
 def _rms(x, w, eps):
@@ -157,61 +147,11 @@ def _attend(q, k, v, pos, seg, window):
 # the forward pass
 # ---------------------------------------------------------------------------
 
-def _layer(x, lw, ls, pos, seg, *, n, eps, theta, window):
-    d, h, kv, hd = n["d"], n["h"], n["kv"], n["hd"]
-    rows = x.shape[0]
-    s = ls if ls is not None else {}
-    a = lw["attn"]
-    xn = _rms(x, lw["ln1"], eps)
-    q = _mm(xn, a["wq"].reshape(d, h * hd), s.get("wq"))
-    k = _mm(xn, a["wk"].reshape(d, kv * hd), s.get("wk"))
-    v = _mm(xn, a["wv"].reshape(d, kv * hd), s.get("wv"))
-    if "bq" in a:
-        q = q + a["bq"].reshape(-1).astype(F32)
-        k = k + a["bk"].reshape(-1).astype(F32)
-        v = v + a["bv"].reshape(-1).astype(F32)
-    q = _rope(q.reshape(rows, h, hd), pos, theta)
-    k = _rope(k.reshape(rows, kv, hd), pos, theta)
-    o = _attend(q, k, v.reshape(rows, kv, hd), pos, seg, window)
-    x = x + _mm(o, a["wo"].reshape(h * hd, d), s.get("wo"))
-
-    m = lw["mlp"]
-    xn = _rms(x, lw["ln2"], eps)
-    blk = min(MLP_BLOCK, rows)
-
-    def mlp(xb):
-        up = jax.nn.silu(_mm(xb, m["w1"], s.get("w1"))) \
-            * _mm(xb, m["w3"], s.get("w3"))
-        return _mm(up, m["w2"], s.get("w2"))
-
-    y = jax.lax.map(mlp, xn.reshape(rows // blk, blk, d))
-    return x + y.reshape(rows, d)
-
-
 @partial(jax.jit, static_argnames=("cfg_key", "window"))
 def _forward(params, scales, toks, pos, seg, want, *, cfg_key, window):
     cj = dict(cfg_key)
-    n = weights.dims(cj)
-    eps, theta = float(cj["rms_norm_eps"]), float(cj["rope_theta"])
-    sc = scales or {}
-    x = params["embed"][toks].astype(F32)
-    if "embed" in sc:
-        x = x * sc["embed"][toks][:, None]
-    blockp = params["pattern"]["00_attn"]
-    blocks = sc.get("pattern")
-
-    def body(x, xs):
-        lw, ls = xs
-        return _layer(x, lw, ls, pos, seg, n=n, eps=eps, theta=theta,
-                      window=window), None
-
-    x, _ = jax.lax.scan(body, x, (blockp, blocks))
-    hidden = _rms(x[want], params["final_norm"], eps)
-    if n["tied"]:
-        logits = _mm(hidden, params["embed"].T, sc.get("embed"))
-    else:
-        logits = _mm(hidden, params["lm_head"], sc.get("lm_head"))
-    return logits[:, :n["v"]]
+    return spec.family(cj).forward(params, scales, toks, pos, seg, want, cj,
+                                   window)
 
 
 def forward(params, cj: dict, packed, window: int, scales=None):
@@ -232,41 +172,35 @@ def window_rows(max_len: int) -> int:
 # the control: float8 weights
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("axis",), donate_argnums=(0,))
-def _fp8(w, axis):
-    amax = jnp.max(jnp.abs(w.astype(F32)), axis=axis)
+@partial(jax.jit, static_argnames=("axes",), donate_argnums=(0,))
+def _fp8(w, axes):
+    amax = jnp.max(jnp.abs(w.astype(F32)), axis=axes)
     scale = jnp.maximum(amax, 1e-30) / FP8_MAX
-    s = jnp.expand_dims(scale, axis)
+    s = jnp.expand_dims(scale, axes)
     q = jnp.clip(w.astype(F32) / s, -FP8_MAX, FP8_MAX)
     return q.astype(jnp.float8_e4m3fn), scale
 
 
-def quantize(params: dict):
-    """Replace every matrix of ``params`` by float8 e4m3 with a scale per
-    output column, leaf by leaf so the peak stays near the weights' size.
-    Returns (params in float8, the scales tree for ``forward``)."""
-    blk = params["pattern"]["00_attn"]
-    scales = {"pattern": {"attn": {}, "mlp": {}}}
-    for group in ("attn", "mlp"):
-        for name in list(blk[group]):
-            if name not in _MATRICES:
-                continue
-            w = blk[group][name]
-            shape = w.shape
-            if name == "wo":   # (L, h, hd, d): the input axis is h*hd
-                w = w.reshape(shape[0], shape[1] * shape[2], shape[3])
-            q, s = _fp8(w, 1)
-            blk[group][name] = q.reshape(shape)
-            scales["pattern"][group][name] = s.reshape(shape[0], -1)
-    # embedding rows double as the tied head's output columns
-    params["embed"], scales["embed"] = _fp8(params["embed"], 1)
-    if "lm_head" in params:
-        params["lm_head"], scales["lm_head"] = _fp8(params["lm_head"], 0)
-    flat = {}
-    for group in ("attn", "mlp"):
-        flat.update(scales["pattern"][group])
-    scales["pattern"] = flat
-    return params, scales
+def quantize(params: dict, cj: dict):
+    """Replace every matrix the family lists in ``MATRICES`` (by leaf name,
+    with its input axes) by float8 e4m3 with a scale per output column,
+    leaf by leaf so the peak stays near the weights' size.  Returns (params
+    in float8, the scales tree for ``forward``: the matrices' paths, each
+    with its scales in the shape of its output axes)."""
+    matrices = spec.family(cj).MATRICES
+
+    def walk(tree: dict) -> dict:
+        scales = {}
+        for name, leaf in tree.items():
+            if isinstance(leaf, dict):
+                inner = walk(leaf)
+                if inner:
+                    scales[name] = inner
+            elif name in matrices:
+                tree[name], scales[name] = _fp8(leaf, matrices[name])
+        return scales
+
+    return params, walk(params)
 
 
 # ---------------------------------------------------------------------------

@@ -168,7 +168,6 @@ def compare_run(setup: Setup, run, stats, violations, control=False):
 def one_run(args, cell, model, peak, root) -> int:
     import compare
     import harness
-    import work
     import xtrace
 
     setup = Setup(cell, args.seed, model)
@@ -180,7 +179,6 @@ def one_run(args, cell, model, peak, root) -> int:
                                  vocab=int(cell.config["vocab_size"]),
                                  params2=setup.params2, trace_dir=trace_dir)
         run.setup_s = setup_s
-        run.dims = work.dims(cell.config)
         run.peak = peak
         device = harness.device_peak()
         stats, violations = setup.finish()

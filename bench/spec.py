@@ -5,6 +5,12 @@ mix and metrics.  Everything else is a file of its own, found by name:
 
 - ``bench/configs/<config>.json``: the model configuration as it is run
   (the path is the config entry's ``file``);
+- ``bench/families/<model_type>.py``: the configuration's model family,
+  named by its ``model_type``: how it maps onto the program's
+  configuration (``arch``), its weight tree (``dims``, ``layout``), the
+  matrices the float8 control quantizes (``MATRICES``), its plain float32
+  reference (``forward``) and the work of each recorded call
+  (``call_work``);
 - ``bench/traffic/<traffic>.json``: the traffic mix's parameters, read by
   the one generator in ``traffic.py``;
 - ``bench/cells/<cell>.json``: the cell's serving settings (nodes,
@@ -13,12 +19,13 @@ mix and metrics.  Everything else is a file of its own, found by name:
 - ``bench/metrics/<metric>.py``: a reader with ``read(run)`` that returns
   the metric's value, or None where the run has nothing to read.
 
-So a later change adds a cell, a mix, a configuration or a metric by adding
-files and entries, and edits none.
+So a later change adds a cell, a mix, a configuration, a model family or a
+metric by adding files and entries, and edits none.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from dataclasses import dataclass, field
@@ -70,6 +77,11 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     path = Path(root) / conf["file"]
     with open(path if path.is_file() else ROOT / conf["file"]) as f:
         config = json.load(f)
+    if "model_type" not in config:
+        raise KeyError(f"{conf['file']} names no model_type")
+    # the generic modules find the family through the configuration
+    config["family_file"] = str(
+        _find(root, "families", config["model_type"], ".py"))
     with open(_find(root, "traffic", w["traffic"], ".json")) as f:
         traffic = json.load(f)
     with open(_find(root, "cells", name, ".json")) as f:
@@ -81,13 +93,30 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         per_layer=[m for m in bench["per_layer"] if applies(m, name)])
 
 
-def reader(metric: str, root: Path = ROOT) -> Callable:
-    path = _find(root, "metrics", metric, ".py")
+def _module(prefix: str, name: str, path: Path):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+        prefix + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str, root: Path = ROOT) -> Callable:
+    return _module("bench_metric_", metric,
+                   _find(root, "metrics", metric, ".py")).read
+
+
+def family(cj: dict):
+    """The module of a configuration's model family: the file
+    ``load_cell`` found for its ``model_type``, else this directory's
+    ``families/<model_type>.py``."""
+    return _family(cj.get("family_file") or str(
+        _find(ROOT, "families", cj["model_type"], ".py")))
+
+
+@functools.lru_cache(maxsize=None)
+def _family(path: str):
+    return _module("bench_family_", Path(path).stem, Path(path))
 
 
 def read_metrics(metrics: List[dict], run, root: Path = ROOT

@@ -55,7 +55,7 @@ def test_reference_matches_a_plain_loop():
 
     import weights
 
-    cj = {"hidden_size": 64, "num_attention_heads": 4,
+    cj = {"model_type": "qwen2", "hidden_size": 64, "num_attention_heads": 4,
           "num_key_value_heads": 2, "intermediate_size": 96,
           "vocab_size": 300, "num_hidden_layers": 2,
           "tie_word_embeddings": True, "rope_theta": 10000.0,

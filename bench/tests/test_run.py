@@ -4,6 +4,8 @@ a tiny rehearsal on the CPU prints the contract's last line."""
 import json
 import subprocess
 import sys
+import threading
+import time
 from types import SimpleNamespace
 
 import pytest
@@ -105,3 +107,68 @@ def test_tiny_rehearsal_prints_the_contract_line(capsys, workload, trace):
     # the numbers compared are also the last lines of standard error
     assert err.strip().splitlines()[-1].startswith(
         list(res["compared"])[-1] + ":")
+
+
+def traced_window(monkeypatch, tmp_path, stop_delay_s):
+    """One traced window of the tiny chat cell whose ``stop_trace`` takes
+    ``stop_delay_s`` longer; returns (the run or the error raised, the
+    profile options passed, an event set once the real stop returned)."""
+    import jax
+
+    import harness
+    import spec
+    from repro.models import Model
+
+    cell = spec.load_cell("tiny.chat", TINY)
+    setup = run.Setup(cell, 7, Model(harness.arch_config(cell.config)))
+    options, stopped = [], threading.Event()
+    start, stop = jax.profiler.start_trace, jax.profiler.stop_trace
+
+    def slow_start(log_dir, *args, profiler_options=None, **kwargs):
+        options.append(profiler_options)
+        start(log_dir, *args, profiler_options=profiler_options, **kwargs)
+
+    def slow_stop():
+        time.sleep(stop_delay_s)
+        stop()
+        stopped.set()
+
+    monkeypatch.setattr(jax.profiler, "start_trace", slow_start)
+    monkeypatch.setattr(jax.profiler, "stop_trace", slow_stop)
+    try:
+        got = harness.run_window(setup.system, cell, 7, 2.0,
+                                 vocab=int(cell.config["vocab_size"]),
+                                 trace_dir=str(tmp_path))
+    except harness.TraceTimeout as exc:
+        got = exc
+    finally:
+        setup.finish()
+    return got, options, stopped
+
+
+def test_a_slow_stop_trace_still_gives_the_traced_span(monkeypatch,
+                                                       tmp_path):
+    """The join used to end ``GRACE_S`` past the window, at least 1 s
+    after the last answer: a stop slower than that left ``trace_t`` unset
+    and the rooflines silent."""
+    import jax
+
+    import harness
+
+    monkeypatch.setattr(harness, "GRACE_S", 0.5)
+    got, options, stopped = traced_window(monkeypatch, tmp_path, 4.0)
+    assert stopped.is_set()
+    a, b = got.trace_t
+    assert got.t0 < a < b < got.t1
+    assert [o.python_tracer_level for o in options] == [0]
+    assert options[0].host_tracer_level == \
+        jax.profiler.ProfileOptions().host_tracer_level
+
+
+def test_a_stop_trace_past_its_bound_is_an_error(monkeypatch, tmp_path):
+    import harness
+
+    monkeypatch.setattr(harness, "STOP_TRACE_S", 1.0)
+    got, _, stopped = traced_window(monkeypatch, tmp_path, 4.0)
+    assert isinstance(got, harness.TraceTimeout)
+    assert stopped.wait(30)

@@ -94,3 +94,26 @@ def test_config_sizes_match_the_program_or_are_listed():
         bad = dict(cj, hidden_size=1024)
         with pytest.raises(ValueError):
             harness.arch_config(bad)
+
+
+def test_a_bursty_metric_reads_as_its_base_in_other_cells():
+    """``<m>.bursty`` is ``<m>`` in the cells where the tail has no bound:
+    the same reader, other cells, and tied to an end-to-end metric those
+    cells report."""
+    from types import SimpleNamespace as NS
+
+    bench = spec.load_benchmark()
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    bursty = [n for n in per_layer if n.endswith(".bursty")]
+    assert "req_p95_ms.bursty" in bursty
+    reqs = [NS(due=0.1 * i, sent=0.1 * i + 0.001 * (i % 7),
+               done=0.1 * i + 1.0 + 0.05 * (i % 13), ok=i % 17 != 0)
+            for i in range(100)]
+    run = NS(requests=reqs, t1=12.0)
+    for name in bursty:
+        base = name[:-len(".bursty")]
+        if base in per_layer:
+            assert not set(per_layer[base]["workloads"]) & \
+                set(per_layer[name]["workloads"])
+        if base in ("req_p95_ms", "gen_late_ms.p99"):
+            assert spec.reader(name)(run) == spec.reader(base)(run) > 0

@@ -45,3 +45,36 @@ def test_lengths_stay_in_their_ranges():
             assert m["prompt"]["min"] <= len(r.prompt) <= m["prompt"]["max"]
             assert m["output"]["min"] <= r.max_new <= m["output"]["max"]
             assert all(1 <= t < 1000 for t in r.prompt)
+
+
+def test_size_groups_keep_each_burst_s_work():
+    m = mix("rag")
+    assert m["order"]["kind"] == "size_groups"
+    k, n = int(m["order"]["group"]), 96
+    runs = [traffic.make_requests(m, n, 1000, s) for s in (3, 2**40 + 11)]
+    lens = [np.array([len(r.prompt) for r in rs]) for rs in runs]
+    assert not np.array_equal(lens[0], lens[1])  # the seed still orders
+    assert sorted(lens[0]) == sorted(lens[1])
+    # answers and sessions stay with their arrivals
+    assert [(r.session, r.max_new) for r in runs[0]] == \
+        [(r.session, r.max_new) for r in runs[1]]
+    # each slot keeps a length of its own group of k ranks
+    ranked = np.sort(lens[0])
+    groups = lambda v: {i // k for i in np.flatnonzero(ranked == v)}  # noqa
+    for a, b in zip(*lens):
+        assert groups(a) & groups(b)
+    # so every stretch of arrivals carries the same prompt tokens, to a
+    # group's width
+    for lo in range(0, n, 24):
+        a, b = lens[0][lo:lo + 24].sum(), lens[1][lo:lo + 24].sum()
+        assert abs(a - b) < 0.05 * a
+
+
+def test_an_unknown_order_is_refused():
+    m = dict(mix("rag"), order={"kind": "sorted"})
+    try:
+        traffic.make_requests(m, 10, 1000, 1)
+    except ValueError as exc:
+        assert "sorted" in str(exc)
+    else:
+        raise AssertionError("an unknown order was accepted")

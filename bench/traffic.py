@@ -7,6 +7,14 @@ seed; the run's seed orders the requests over those times and draws the
 token ids.  So two seeds offer the same work, with the same bursts, in
 another order, and the spread between runs is the system's, not the
 draw's.
+
+A mix's optional ``"order"`` says how far the seed may reorder:
+``{"kind": "any"}`` (the default) deals every request to any arrival;
+``{"kind": "size_groups", "group": k}`` deals the prompt lengths of each
+group of ``k`` adjacent lengths over the arrivals that group holds in the
+master draw, and every arrival keeps its master output length and
+session.  So every burst carries the same prompt tokens, to a group's
+width, and the same answers and sessions, whatever the seed.
 """
 
 from __future__ import annotations
@@ -42,24 +50,44 @@ def _sessions(spec: dict, n: int, rng: np.random.Generator) -> np.ndarray:
     return rng.choice(count, size=n, p=w / w.sum())
 
 
+def _order(spec: dict, plens: np.ndarray, rng: np.random.Generator
+           ) -> tuple:
+    """For each arrival slot, drawn by the seed: the master request whose
+    prompt length it gets, and the one whose output length and session."""
+    n = len(plens)
+    if spec["kind"] == "any":
+        order = rng.permutation(n)
+        return order, order
+    if spec["kind"] == "size_groups":
+        k = int(spec["group"])
+        by_size = np.argsort(plens, kind="stable")
+        order = np.arange(n)
+        for j in range(0, n, k):
+            held = by_size[j:j + k]
+            order[held] = rng.permutation(held)
+        return order, np.arange(n)
+    raise ValueError(f"unknown request order {spec['kind']!r}")
+
+
 def seed_rng(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng([int(seed) & (2**64 - 1), stream])
 
 
 def make_requests(traffic: dict, n: int, vocab: int, seed: int
                   ) -> List[Request]:
-    """``n`` requests: the master multiset in the seed's order, with
-    prompt ids drawn from ``[1, vocab)`` by the seed."""
+    """``n`` requests: the master multiset in the seed's order (the mix's
+    ``"order"``), with prompt ids drawn from ``[1, vocab)`` by the seed."""
     master = np.random.default_rng(MASTER_SEED)
     plens = _lengths(traffic["prompt"], n, master)
     outs = _lengths(traffic["output"], n, master)
     sess = _sessions(traffic["sessions"], n, master)
     rng = seed_rng(seed, 1)
-    order = rng.permutation(n)
+    prompts, rest = _order(traffic.get("order", {"kind": "any"}), plens,
+                           rng)
     out = []
-    for i in order:
+    for i, j in zip(prompts, rest):
         prompt = rng.integers(1, vocab, size=int(plens[i])).tolist()
-        out.append(Request(f"s{int(sess[i])}", prompt, int(outs[i])))
+        out.append(Request(f"s{int(sess[j])}", prompt, int(outs[j])))
     return out
 
 
